@@ -129,14 +129,19 @@ def build_serve_context(spec: ServeSpec, params=None) -> ServeContext:
 
 
 def verify_report(report, ctx: ServeContext, requests=None,
-                  n: int = -1, stream_events=None) -> dict:
+                  n: int = -1, stream_events=None,
+                  margin_tol: float = 0.0) -> dict:
     """Check served outputs token-identical to single-request decoding.
 
     ``n`` limits how many requests are replayed through
-    ``reference_generate`` (-1 = all). When the run streamed
+    ``reference_generate`` (-1 = all). A request whose output first
+    diverges where the reference's top-2 logit margin is below
+    ``margin_tol`` is excused instead of failing (``ReportSpec.
+    verify_margin``; 0 keeps the check exact). When the run streamed
     (``stream_events`` from the engine's ``on_token`` hook), the stream
     order is additionally audited against the final token order. Raises
-    RuntimeError listing the diverging rids; returns the audit dict
+    RuntimeError listing each diverging rid with its first diverging
+    token index and the reference's margin there; returns the audit dict
     recorded on the report.
     """
     from repro.runtime.engine import reference_generate
@@ -145,17 +150,36 @@ def verify_report(report, ctx: ServeContext, requests=None,
     k = len(requests) if n < 0 else min(n, len(requests))
     slot_len = ctx.engine.pool.slot_len
     by_rid = {r["rid"]: r["tokens"] for r in report.per_request}
-    mismatches = []
+    mismatches, excused = [], []
     for req in requests[:k]:
         want = reference_generate(ctx.model, ctx.params, req.prompt,
                                   req.max_new_tokens, slot_len)
-        if by_rid[req.rid] != want:
-            mismatches.append(req.rid)
+        got = by_rid[req.rid]
+        if got == want:
+            continue
+        first = next((i for i, (a, b) in enumerate(zip(got, want))
+                      if a != b), min(len(got), len(want)))
+        _, margins = reference_generate(ctx.model, ctx.params, req.prompt,
+                                        req.max_new_tokens, slot_len,
+                                        with_margins=True)
+        row = {"rid": req.rid, "first_diverging_token": first,
+               "top2_margin": (margins[first] if first < len(margins)
+                               else None)}
+        if row["top2_margin"] is not None \
+                and row["top2_margin"] < margin_tol:
+            excused.append(row)
+        else:
+            mismatches.append(row)
     if mismatches:
+        where = [(m["first_diverging_token"], m["top2_margin"])
+                 for m in mismatches]
         raise RuntimeError(
             f"{report.engine} outputs diverge from single-request "
-            f"decoding: rids {mismatches}")
+            f"decoding: rids {[m['rid'] for m in mismatches]} "
+            f"(first diverging token, reference top-2 margin: {where})")
     out = {"checked": k, "mismatches": []}
+    if margin_tol:
+        out["excused"] = excused
     if stream_events is not None:
         out["stream"] = audit_stream(report, stream_events)
     return out
@@ -242,9 +266,9 @@ def run_serve(spec: ServeSpec, ctx: Optional[ServeContext] = None):
                 "".join(json.dumps(ev) + "\n" for ev in events))
         report.stream = audit_stream(report, events)
     if spec.report.verify:
-        report.verified = verify_report(report, ctx, requests=requests,
-                                        n=spec.report.verify,
-                                        stream_events=events)
+        report.verified = verify_report(
+            report, ctx, requests=requests, n=spec.report.verify,
+            stream_events=events, margin_tol=spec.report.verify_margin)
     if tracer is not None:
         tracer.record("serve_report", **{
             k: v for k, v in report.to_json().items()

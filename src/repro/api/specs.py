@@ -686,14 +686,23 @@ class ClockSpec(SpecBase):
 class ReportSpec(SpecBase):
     """Report handling: ``verify`` checks N continuous outputs (-1 = all)
     token-identical against single-request decoding; ``out`` writes the
-    report JSON (without per-request rows unless ``per_request``)."""
+    report JSON (without per-request rows unless ``per_request``).
+
+    ``verify_margin`` (default 0: exact) excuses a request whose output
+    first diverges at a token where the reference's top-2 logit margin is
+    below it — a near-tie that reduced-precision (bf16) arithmetic may
+    break either way. Excused requests are listed, with the diverging
+    index and margin, under ``verified["excused"]``."""
     verify: int = 0
     per_request: bool = True
     out: Optional[str] = None
+    verify_margin: float = 0.0
 
     def validate(self) -> "ReportSpec":
         self._require(self.verify >= -1,
                       "verify must be -1 (all), 0 (off), or a count")
+        self._require(self.verify_margin >= 0,
+                      "verify_margin must be >= 0")
         return self
 
 

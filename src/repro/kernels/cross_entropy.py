@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 _NEG_INF = -1e30
 
 
@@ -39,25 +37,25 @@ def _xent_kernel(h_ref, w_ref, lab_ref, out_ref, m_scr, l_scr, t_scr, *,
 
     h = h_ref[...].astype(jnp.float32)                     # (bt, d)
     w = w_ref[...].astype(jnp.float32)                     # (d, bv)
-    labels = lab_ref[...]                                  # (bt,)
+    labels = lab_ref[...]                                  # (bt, 1)
 
     s = jax.lax.dot_general(h, w, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (bt, bv)
 
-    # online logsumexp
+    # online logsumexp; per-token running values are (bt, 1) columns
     m_prev, l_prev = m_scr[...], l_scr[...]
-    m_cur = s.max(axis=-1)
+    m_cur = s.max(axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     l_new = l_prev * jnp.exp(m_prev - m_new) \
-        + jnp.exp(s - m_new[:, None]).sum(axis=-1)
+        + jnp.exp(s - m_new).sum(axis=-1, keepdims=True)
     m_scr[...] = m_new
     l_scr[...] = l_new
 
     # target logit via one-hot dot (labels local to this vocab block)
-    local = labels - iv * block_v                          # (bt,)
+    local = labels - iv * block_v                          # (bt, 1)
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    onehot = (cols == local[:, None]).astype(jnp.float32)
-    t_scr[...] = t_scr[...] + (s * onehot).sum(axis=-1)
+    onehot = (cols == local).astype(jnp.float32)
+    t_scr[...] = t_scr[...] + (s * onehot).sum(axis=-1, keepdims=True)
 
     @pl.when(iv == num_v_blocks - 1)
     def _finish():
@@ -67,7 +65,11 @@ def _xent_kernel(h_ref, w_ref, lab_ref, out_ref, m_scr, l_scr, t_scr, *,
 
 def fused_cross_entropy(hidden, w_vocab, labels, *, block_t: int = 256,
                         block_v: int = 1024, interpret: bool = False):
-    """hidden: (T, d); w_vocab: (d, V); labels: (T,) int32 → NLL (T,) fp32."""
+    """hidden: (T, d); w_vocab: (d, V); labels: (T,) int32 → NLL (T,) fp32.
+
+    Labels, output and scratch are (T, 1) columns: Mosaic tiles the last
+    two dimensions of a block, and a 1-D block's layout disagrees with the
+    one XLA gives the operand."""
     t, d = hidden.shape
     v = w_vocab.shape[1]
     block_t = min(block_t, t)
@@ -78,22 +80,23 @@ def fused_cross_entropy(hidden, w_vocab, labels, *, block_t: int = 256,
 
     kernel = functools.partial(_xent_kernel, block_v=block_v,
                                num_v_blocks=nv)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(nt, nv),
         in_specs=[
             pl.BlockSpec((block_t, d), lambda ti, vi: (ti, 0)),
             pl.BlockSpec((d, block_v), lambda ti, vi: (0, vi)),
-            pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
+            pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
         ],
-        out_specs=pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
-        out_shape=jax.ShapeDtypeStruct((t,), jnp.float32),
+        out_specs=pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
+        out_shape=jax.ShapeDtypeStruct((t, 1), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((block_t,), jnp.float32),
-            pltpu.VMEM((block_t,), jnp.float32),
-            pltpu.VMEM((block_t,), jnp.float32),
+            pltpu.VMEM((block_t, 1), jnp.float32),
+            pltpu.VMEM((block_t, 1), jnp.float32),
+            pltpu.VMEM((block_t, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(hidden, w_vocab, labels)
+    )(hidden, w_vocab, labels.reshape(t, 1))
+    return out[:, 0]

@@ -8,7 +8,8 @@ separate single-token decode calls (repro.kernels.paged_attention).
 
 Same structure as the decode kernel: the page table is a scalar-prefetch
 operand (``pltpu.PrefetchScalarGridSpec``) so the BlockSpec index map
-walks logical pages into physical-page DMAs; grid = (batch,
+walks logical pages into physical-page DMAs, and so are the window
+positions, which the kernel reads as scalars from SMEM; grid = (batch,
 logical_pages) with the page axis innermost and sequential
 ("arbitrary"), so the online-softmax running max / denominator /
 accumulator carry a leading window axis in VMEM scratch across the page
@@ -30,14 +31,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 _NEG_INF = -1e30
 
 
-def _verify_kernel(table_ref, q_ref, qp_ref, k_ref, v_ref, o_ref,
+def _verify_kernel(table_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, scale: float, page_size: int,
                    rep: int, num_logical: int):
+    bi = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -47,7 +47,6 @@ def _verify_kernel(table_ref, q_ref, qp_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0].astype(jnp.float32)                   # (W, Hq, D)
-    qp = qp_ref[0]                                     # (W,) int32
     k = k_ref[0].astype(jnp.float32)                   # (P, Hkv, D)
     v = v_ref[0].astype(jnp.float32)
     w, hq, d = q.shape
@@ -64,9 +63,14 @@ def _verify_kernel(table_ref, q_ref, qp_ref, k_ref, v_ref, o_ref,
     s = jnp.swapaxes(s.reshape(hkv, w, rep, page_size), 0, 1)
     s = s.reshape(w, hq, page_size)                    # (W, Hq, P)
 
+    # (W, 1, 1) window positions assembled from the SMEM scalars
+    lane = jax.lax.broadcasted_iota(jnp.int32, (w, 1, 1), 0)
+    qp = jnp.zeros((w, 1, 1), jnp.int32)
+    for i in range(w):
+        qp = jnp.where(lane == i, qp_ref[bi, i], qp)
     k_pos = j * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, page_size), 2)
-    s = jnp.where(k_pos <= qp[:, None, None], s, _NEG_INF)
+    s = jnp.where(k_pos <= qp, s, _NEG_INF)
 
     m_prev, l_prev = m_scr[...], l_scr[...]            # (W, Hq)
     m_cur = s.max(axis=-1)
@@ -105,20 +109,18 @@ def spec_verify(q, k_pages, v_pages, page_table, q_pos, *,
         num_logical=m)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, m),
         in_specs=[
             pl.BlockSpec((1, w, hq, d),
-                         lambda bi, j, table: (bi, 0, 0, 0)),
-            pl.BlockSpec((1, w),
-                         lambda bi, j, table: (bi, 0)),
+                         lambda bi, j, table, qp: (bi, 0, 0, 0)),
             pl.BlockSpec((1, page_size, hkv, d),
-                         lambda bi, j, table: (table[bi, j], 0, 0, 0)),
+                         lambda bi, j, table, qp: (table[bi, j], 0, 0, 0)),
             pl.BlockSpec((1, page_size, hkv, d),
-                         lambda bi, j, table: (table[bi, j], 0, 0, 0)),
+                         lambda bi, j, table, qp: (table[bi, j], 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, w, hq, d),
-                               lambda bi, j, table: (bi, 0, 0, 0)),
+                               lambda bi, j, table, qp: (bi, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((w, hq), jnp.float32),
             pltpu.VMEM((w, hq), jnp.float32),
@@ -129,8 +131,8 @@ def spec_verify(q, k_pages, v_pages, page_table, q_pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, w, hq, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), q, q_pos.astype(jnp.int32),
+    )(page_table.astype(jnp.int32), q_pos.astype(jnp.int32), q,
       k_pages, v_pages)

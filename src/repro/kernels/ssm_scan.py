@@ -14,13 +14,12 @@ the state never round-trips. The channel axis is tiled over `block_d`
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams
 
 
 def _ssm_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr,
@@ -31,24 +30,30 @@ def _ssm_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr,
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0].astype(jnp.float32)          # (bl, bd)
-    dt = dt_ref[0].astype(jnp.float32)        # (bl, bd)
     a = a_ref[...].astype(jnp.float32)        # (bd, N)
-    bm = b_ref[0].astype(jnp.float32)         # (bl, N)
-    cm = c_ref[0].astype(jnp.float32)         # (bl, N)
+    # Mosaic lowers no dynamic slice of a value, and a one-row dynamic ref
+    # load must start on a sublane-tile boundary: the loop reads and writes
+    # sublane-aligned chunks of rows through pl.ds and walks each chunk's
+    # rows with static slices.
+    chunk = math.gcd(block_l, 8)
 
-    def step(t, carry):
-        h, ys = carry
-        a_bar = jnp.exp(dt[t][:, None] * a)               # (bd, N)
-        h = a_bar * h + (dt[t] * x[t])[:, None] * bm[t][None, :]
-        y = (h * cm[t][None, :]).sum(axis=1)              # (bd,)
-        ys = jax.lax.dynamic_update_index_in_dim(ys, y, t, 0)
-        return h, ys
+    def step(c, h):
+        s = pl.multiple_of(c * chunk, chunk)
+        rows = pl.ds(s, chunk)
+        x = x_ref[0, rows, :].astype(jnp.float32)         # (chunk, bd)
+        dt = dt_ref[0, rows, :].astype(jnp.float32)
+        bm = b_ref[0, rows, :].astype(jnp.float32)        # (chunk, N)
+        cm = c_ref[0, rows, :].astype(jnp.float32)
+        dt_cols, dx_cols = dt.T, (dt * x).T               # (bd, chunk)
+        ys = []
+        for t in range(chunk):
+            a_bar = jnp.exp(dt_cols[:, t:t + 1] * a)      # (bd, N)
+            h = a_bar * h + dx_cols[:, t:t + 1] * bm[t:t + 1]
+            ys.append((h * cm[t:t + 1]).sum(axis=1, keepdims=True))
+        y_ref[0, rows, :] = jnp.concatenate(ys, axis=1).T.astype(y_ref.dtype)
+        return h
 
-    ys0 = jnp.zeros((block_l, x.shape[1]), jnp.float32)
-    h, ys = jax.lax.fori_loop(0, block_l, step, (h_scr[...], ys0))
-    h_scr[...] = h
-    y_ref[0] = ys.astype(y_ref.dtype)
+    h_scr[...] = jax.lax.fori_loop(0, block_l // chunk, step, h_scr[...])
 
     @pl.when(il == num_l_blocks - 1)
     def _finish():
@@ -96,7 +101,7 @@ def ssm_scan(x, dt, a, bmat, cmat, *, block_l: int = 64,
             jax.ShapeDtypeStruct((bsz, d, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a, bmat, cmat)

@@ -40,7 +40,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro import sharding as shard_lib
@@ -167,9 +166,28 @@ class ShardedPSLEngine:
             return jax.device_put(batch, self.batch_shardings(batch))
 
     # -------------------------------------------------------------- step
+    def _constrained(self, fn: Callable) -> Callable:
+        """``fn`` traced with the residual stream's batch axis pinned to
+        the profile's batch axes.
+
+        Under fsdp/ddp the weights are sharded over the same mesh axes as
+        the batch; left alone, GSPMD may resolve that conflict by
+        gathering the batch onto every device, which replicates every
+        activation (and, at full width, overflows HBM). The constraint
+        keeps activations batch-sharded and gathers weights instead. The
+        tp profile keeps its own propagation."""
+        if self.profile not in ("fsdp", "ddp"):
+            return fn
+        ns = shard_lib.activation_sharding_for(self.mesh, "batch")
+
+        def traced(*args):
+            with shard_lib.activation_sharding(ns):
+                return fn(*args)
+        return traced
+
     def _build_gspmd(self, batch) -> Callable:
-        step = make_train_step(self.model, self.optimizer,
-                               microbatches=self.microbatches)
+        step = self._constrained(make_train_step(
+            self.model, self.optimizer, microbatches=self.microbatches))
         rep = shard_lib.replicated(self.mesh)
         metrics_sh = {k: rep for k in _METRIC_KEYS}
         return jax.jit(step,
@@ -210,10 +228,10 @@ class ShardedPSLEngine:
         batch_specs = jax.tree_util.tree_map(
             lambda _: PartitionSpec("data"), batch)
         metrics_specs = {k: rep for k in _METRIC_KEYS}
-        mapped = shard_map(per_shard, mesh=mesh,
-                           in_specs=(state_specs, batch_specs),
-                           out_specs=(state_specs, metrics_specs),
-                           check_rep=False)
+        mapped = jax.shard_map(per_shard, mesh=mesh,
+                               in_specs=(state_specs, batch_specs),
+                               out_specs=(state_specs, metrics_specs),
+                               check_vma=False)
         return jax.jit(mapped,
                        donate_argnums=(0,) if self.donate else ())
 
@@ -241,8 +259,9 @@ class ShardedPSLEngine:
 
         with self.mesh:
             if self.lowering == "gspmd":
-                fn = jax.jit(g, in_shardings=(self.params_sh,
-                                              self.batch_shardings(batch)))
+                fn = jax.jit(self._constrained(g),
+                             in_shardings=(self.params_sh,
+                                           self.batch_shardings(batch)))
                 return fn(state.params, batch)
 
             def per_shard(params, local_batch):
@@ -260,7 +279,7 @@ class ShardedPSLEngine:
                                          self.params_sh)
             batch_specs = jax.tree_util.tree_map(
                 lambda _: PartitionSpec("data"), batch)
-            fn = jax.jit(shard_map(per_shard, mesh=self.mesh,
-                                   in_specs=(rep, batch_specs),
-                                   out_specs=rep, check_rep=False))
+            fn = jax.jit(jax.shard_map(per_shard, mesh=self.mesh,
+                                       in_specs=(rep, batch_specs),
+                                       out_specs=rep, check_vma=False))
             return fn(state.params, batch)

@@ -25,6 +25,7 @@ import argparse
 from typing import List
 
 from repro import api
+from repro.launch.compile_cache import enable_compile_cache
 # legacy re-exports: the static engine moved into the runtime package
 from repro.runtime.static import BatchedServer, Request  # noqa: F401
 
@@ -150,6 +151,7 @@ def main(argv=None):
                          "(ExperimentSpec execution.checkpoint)")
     ap.add_argument("--seed", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.config:
         spec = api.load_any_spec(args.config)

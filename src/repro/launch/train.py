@@ -23,6 +23,7 @@ import numpy as np
 
 from repro import api
 from repro.data.federated import build_lm_client_store as _build_lm_store
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import TrainState
 
 
@@ -195,6 +196,7 @@ def main(argv=None):
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--seed", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.config:
         spec = api.load_any_spec(args.config)

@@ -30,6 +30,22 @@ def tree_map_specs(fn, spec_tree):
                                   is_leaf=lambda x: is_spec(x))
 
 
+# Leading axes that stack independent weights (scanned layers, expert banks)
+# rather than feed one output unit.
+_STACK_AXES = ("layers", "experts")
+
+
+def _fan_in(spec) -> int:
+    """Inputs feeding one output unit of a weight whose last axis is the
+    output: every other axis but the stacking ones — ``in`` for an
+    (in, out) matrix, ``kh*kw*cin`` for an HWIO conv kernel, ``in`` for a
+    (layers, in, out) stack."""
+    if len(spec.shape) == 1:
+        return spec.shape[0]
+    return math.prod(n for n, ax in zip(spec.shape[:-1], spec.axes[:-1])
+                     if ax not in _STACK_AXES)
+
+
 def materialize(spec_tree, key, dtype) -> Any:
     """Randomly initialize parameters from a spec tree."""
     leaves, treedef = jax.tree_util.tree_flatten(spec_tree, is_leaf=is_spec)
@@ -50,8 +66,7 @@ def materialize(spec_tree, key, dtype) -> Any:
             base = jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))
             arr = jnp.broadcast_to(base, spec.shape).astype(jnp.float32)
         else:  # fan-in scaled normal
-            fan_in = spec.shape[0] if len(spec.shape) > 1 else spec.shape[-1]
-            std = spec.scale / math.sqrt(max(fan_in, 1))
+            std = spec.scale / math.sqrt(max(_fan_in(spec), 1))
             arr = (jax.random.normal(k, spec.shape, jnp.float32)
                    * std).astype(dt)
         out.append(arr)

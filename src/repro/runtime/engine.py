@@ -576,21 +576,31 @@ def _reference_fns(model, cache_len: int):
 
 
 def reference_generate(model, params, prompt: np.ndarray,
-                       max_new_tokens: int, cache_len: int) -> List[int]:
+                       max_new_tokens: int, cache_len: int, *,
+                       with_margins: bool = False):
     """Single-request greedy decoding — the runtime's ground truth.
 
     Exact-length batch-1 prefill followed by one decode step per token, the
     same code path a continuous slot takes, with nothing else in the batch.
+    Returns the token list; with ``with_margins`` also the top-1 minus
+    top-2 logit margin behind each token (how near a tie each pick was).
     """
     prefill, decode = _reference_fns(model, cache_len)
+    margins: List[float] = []
+
+    def pick(row) -> int:
+        if with_margins:
+            top2 = np.asarray(jax.lax.top_k(row, 2)[0], np.float64)
+            margins.append(float(top2[0] - top2[1]))
+        return int(jnp.argmax(row))
+
     logits, cache, pos = prefill(params,
                                  {"tokens": jnp.asarray(prompt[None])})
-    toks = [int(jnp.argmax(logits[0]))]
+    toks = [pick(logits[0])]
     posv = jnp.asarray([int(pos)], jnp.int32)
-    tok = jnp.asarray([[toks[-1]]], jnp.int32)
     for _ in range(max_new_tokens - 1):
+        tok = jnp.asarray([[toks[-1]]], jnp.int32)
         logits, cache = decode(params, cache, tok, posv)
         posv = posv + 1
-        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-        toks.append(int(tok[0, 0]))
-    return toks
+        toks.append(pick(logits[0, -1]))
+    return (toks, margins) if with_margins else toks

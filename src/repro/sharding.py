@@ -16,6 +16,7 @@ is recorded (surfaced in the dry-run report instead of failing the lowering).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -193,6 +194,18 @@ _ACTIVATION_SHARDING: Optional[NamedSharding] = None
 def set_activation_sharding(ns: Optional[NamedSharding]) -> None:
     global _ACTIVATION_SHARDING
     _ACTIVATION_SHARDING = ns
+
+
+@contextlib.contextmanager
+def activation_sharding(ns: Optional[NamedSharding]):
+    """Set the activation constraint for one block (e.g. one trace) and
+    restore the previous one after it."""
+    prev = _ACTIVATION_SHARDING
+    set_activation_sharding(ns)
+    try:
+        yield
+    finally:
+        set_activation_sharding(prev)
 
 
 def constrain_activation(x):

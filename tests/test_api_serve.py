@@ -186,6 +186,36 @@ def test_api_run_serve_token_identical_fifo_and_ljf(served_ctx):
                 req.max_new_tokens, served_ctx.engine.pool.slot_len)
 
 
+def test_verify_report_locates_divergence_and_excuses_near_ties(
+        served_ctx):
+    """A divergence is reported with its first diverging token and the
+    reference's top-2 logit margin there. A margin tolerance above that
+    margin excuses it; the default tolerance of 0 keeps the check exact."""
+    from repro.runtime import reference_generate
+    spec = tiny_serve_spec()
+    report = api.run_serve(spec, ctx=served_ctx)
+    row = next(r for r in report.per_request if len(r["tokens"]) >= 2)
+    row["tokens"][1] = (row["tokens"][1] + 1) \
+        % served_ctx.engine.cfg.vocab_size
+    with pytest.raises(RuntimeError, match=rf"rids \[{row['rid']}\]"):
+        api.verify_report(report, served_ctx)
+    audit = api.verify_report(report, served_ctx, margin_tol=float("inf"))
+    assert audit["mismatches"] == []
+    [excused] = audit["excused"]
+    assert excused["rid"] == row["rid"]
+    assert excused["first_diverging_token"] == 1
+    req = next(r for r in api.build_workload(
+        spec, served_ctx.engine.cfg.vocab_size) if r.rid == row["rid"])
+    toks, margins = reference_generate(
+        served_ctx.model, served_ctx.params, req.prompt,
+        req.max_new_tokens, served_ctx.engine.pool.slot_len,
+        with_margins=True)
+    assert toks == reference_generate(
+        served_ctx.model, served_ctx.params, req.prompt,
+        req.max_new_tokens, served_ctx.engine.pool.slot_len)
+    assert excused["top2_margin"] == margins[1] >= 0.0
+
+
 def test_run_serve_with_arrivals_keeps_admission_invariant(served_ctx):
     spec = tiny_serve_spec().replace(
         workload=tiny_serve_spec().workload.replace(

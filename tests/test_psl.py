@@ -90,11 +90,12 @@ def test_train_step_reduces_loss():
     state = TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
     batch = _cnn_batch(32)
     losses = []
-    for _ in range(20):
+    # fan-in-scaled conv init starts at ~ln(10); 30 steps reach the 20% cut
+    for _ in range(30):
         state, m = step(state, batch)
         losses.append(float(m["loss"]))
     assert losses[-1] < losses[0] * 0.8
-    assert int(state.step) == 20
+    assert int(state.step) == 30
 
 
 def test_cut_transfer_bytes():
