@@ -10,9 +10,11 @@ ExperimentSpec; the legacy ``repro.frameworks`` trainers build it from
 already-constructed objects — both end here.
 
 Telemetry (``ctx.spec.obs``, repro.obs): when enabled, the loop wraps each
-phase in tracer spans — ``plan`` (epoch planning), ``batch`` (host batch
-assembly, one per step), ``device_step`` (the strategy's jit step), and
-``eval`` (end-of-epoch callbacks) under per-epoch ``epoch`` spans — and
+phase in tracer spans under per-epoch ``epoch`` spans: ``plan`` (epoch
+planning), then per step ``batch`` (host batch assembly; the strategy may
+open children inside it through ``ctx.tracer``), ``step`` (the strategy's
+step call, from entry until it returns) and ``callbacks`` (the monitor and
+the ``step_end`` callbacks), and ``eval`` (end-of-epoch callbacks). It
 feeds each step's plan segment to a live GPSL invariant monitor
 (repro.obs.monitor), whose per-epoch summaries land in
 ``record.extras["gpsl_monitor"]``. Instrumentation touches no RNG and no
@@ -25,7 +27,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from repro.api.events import EventBus
 from repro.api.registry import ProtocolStrategy
-from repro.obs import (maybe_jax_profiler, monitor_from_spec,
+from repro.obs import (maybe_jax_profiler, monitor_from_spec, null_tracer,
                        tracer_from_spec, write_outputs)
 
 
@@ -71,6 +73,7 @@ class RunContext:
     spec: Any                       # ExperimentSpec (or a spec-like shim)
     seed: int = 0
     mesh: Any = None                # prebuilt device mesh (sharded engine)
+    tracer: Any = dataclasses.field(default_factory=null_tracer)  # fit() sets
 
     @property
     def protocol(self):
@@ -121,13 +124,15 @@ def fit(ctx: RunContext, strategy: ProtocolStrategy,
 
     ``tracer`` defaults to one built from ``ctx.spec.obs`` (the shared
     no-op NullTracer when absent or disabled); pass an explicit
-    ``repro.obs.Tracer`` to collect spans programmatically.
+    ``repro.obs.Tracer`` to collect spans programmatically. The strategy
+    sees it as ``ctx.tracer``.
     """
     obs = getattr(ctx.spec, "obs", None)
     if tracer is None:
         tracer = tracer_from_spec(
             obs, meta={"kind": "train",
                        "protocol": getattr(ctx.protocol, "name", "?")})
+    ctx = dataclasses.replace(ctx, tracer=tracer)
     record = RunRecord()
     bus = EventBus(callbacks, ctx, record)
     pstate = strategy.setup(ctx)
@@ -156,17 +161,18 @@ def fit(ctx: RunContext, strategy: ProtocolStrategy,
                         item = next(batches, _END)
                     if item is _END:
                         break
-                    if monitor is not None \
-                            and epoch_step < plan.num_steps:
-                        monitor.observe_plan_step(plan, epoch_step)
-                    with tracer.span("device_step", cat="step",
-                                     epoch=epoch, step=record.steps):
+                    with tracer.span("step", cat="step", epoch=epoch,
+                                     step=record.steps):
                         pstate, metrics = strategy.step(ctx, pstate, item)
                     record.step_metrics.append(metrics)
                     record.steps += 1
+                    with tracer.span("callbacks", cat="step"):
+                        if monitor is not None \
+                                and epoch_step < plan.num_steps:
+                            monitor.observe_plan_step(plan, epoch_step)
+                        bus.emit("step_end", epoch=epoch, step=record.steps,
+                                 metrics=metrics, info=item.info)
                     epoch_step += 1
-                    bus.emit("step_end", epoch=epoch, step=record.steps,
-                             metrics=metrics, info=item.info)
                     if max_steps is not None and record.steps >= max_steps:
                         stop = True
                         break

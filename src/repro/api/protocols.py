@@ -28,6 +28,22 @@ from repro.data.federated import GlobalBatchIterator
 from repro.optim import TrainState
 
 
+_END = object()
+
+
+def _drawn(tracer, draws) -> Iterator[Any]:
+    """``draws``, each ``next`` under a ``batch.draw`` span (the span
+    closes before the item is handed on, so none stays open across a
+    ``yield``)."""
+    it = iter(draws)
+    while True:
+        with tracer.span("batch.draw", cat="data"):
+            item = next(it, _END)
+        if item is _END:
+            return
+        yield item
+
+
 def _fresh_state(model, optimizer, seed: int) -> TrainState:
     params = model.init(jax.random.PRNGKey(seed))
     return TrainState(params, optimizer.init(params),
@@ -300,26 +316,31 @@ class PSLStrategy(ProtocolStrategy):
             plan_format=ctx.sampler.plan_format, **ctx.sampler.kwargs)
 
     def epoch_batches(self, ctx, pstate, plan, epoch) -> Iterator[StepItem]:
+        """One item per step; each host draw runs under a ``batch.draw``
+        span and each put to the device under ``batch.put``."""
         engine = pstate["engine"]
+        tracer = ctx.tracer
         if engine is None:
-            it = GlobalBatchIterator(ctx.data.store, plan,
-                                     ctx.protocol.aggregation,
-                                     seed=ctx.seed * 1000 + epoch)
-            for gb in it:
-                yield StepItem(batch_from(gb["features"], gb["labels"],
-                                          gb["weights"]))
+            for gb in _drawn(tracer, GlobalBatchIterator(
+                    ctx.data.store, plan, ctx.protocol.aggregation,
+                    seed=ctx.seed * 1000 + epoch)):
+                with tracer.span("batch.put", cat="data"):
+                    batch = batch_from(gb["features"], gb["labels"],
+                                       gb["weights"])
+                yield StepItem(batch)
         elif ctx.data.kind == "synthetic_lm":
-            for host in lm_plan_batches(ctx.data.lm_data, ctx.data.pop,
-                                        plan, ctx.data.seq_len,
-                                        ctx.protocol.aggregation,
-                                        pstate["shard_of_client"],
-                                        seed=ctx.seed + epoch):
-                yield StepItem(engine.put_batch(host))
+            for host in _drawn(tracer, lm_plan_batches(
+                    ctx.data.lm_data, ctx.data.pop, plan, ctx.data.seq_len,
+                    ctx.protocol.aggregation, pstate["shard_of_client"],
+                    seed=ctx.seed + epoch)):
+                with tracer.span("batch.put", cat="data"):
+                    batch = engine.put_batch(host)
+                yield StepItem(batch)
         else:
-            for gb in GlobalBatchIterator(ctx.data.store, plan,
-                                          ctx.protocol.aggregation,
-                                          seed=ctx.seed * 1000 + epoch,
-                                          num_shards=engine.num_shards):
+            for gb in _drawn(tracer, GlobalBatchIterator(
+                    ctx.data.store, plan, ctx.protocol.aggregation,
+                    seed=ctx.seed * 1000 + epoch,
+                    num_shards=engine.num_shards)):
                 info = None
                 if ctx.protocol.track_tpe:
                     from repro.launch.distributed import step_timing
@@ -330,10 +351,11 @@ class PSLStrategy(ProtocolStrategy):
                                      base_step_ms=ctx.protocol.base_step_ms)
                     info = {"step_ms": tm.step_ms,
                             "shard_skew_ms": tm.shard_skew_ms}
-                batch = engine.put_batch({    # host numpy → one sharded put
-                    "images": np.asarray(gb["features"], np.float32),
-                    "labels": np.asarray(gb["labels"], np.int32),
-                    "weights": np.asarray(gb["weights"], np.float32)})
+                with tracer.span("batch.put", cat="data"):
+                    batch = engine.put_batch({  # host numpy → one sharded put
+                        "images": np.asarray(gb["features"], np.float32),
+                        "labels": np.asarray(gb["labels"], np.int32),
+                        "weights": np.asarray(gb["weights"], np.float32)})
                 yield StepItem(batch, info=info)
 
     def step(self, ctx, pstate, item: StepItem):

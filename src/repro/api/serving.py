@@ -246,7 +246,8 @@ def run_serve(spec: ServeSpec, ctx: Optional[ServeContext] = None):
         tracer = tracer_from_spec(
             obs, clock=clock.now,
             meta={"kind": "serve", "engine": spec.engine.name,
-                  "clock": spec.clock.kind})
+                  "clock": spec.clock.kind},
+            wall_clock=spec.clock.kind == "wall")
     requests = build_workload(spec, ctx.engine.cfg.vocab_size)
     stream = getattr(spec, "stream", None)
     events: Optional[List[dict]] = None

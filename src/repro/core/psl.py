@@ -192,9 +192,10 @@ def make_train_step(model, optimizer: Optimizer, donate: bool = True,
                 return model.loss_fn(params, batch)
             (total, metrics), grads = jax.value_and_grad(
                 loss, has_aux=True)(state.params)
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        params = apply_updates(state.params, updates)
+        with jax.named_scope("psl.update"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = apply_updates(state.params, updates)
         metrics = dict(metrics)
         metrics["grad_norm"] = _grad_norm(grads)
         return TrainState(params=params, opt_state=opt_state,

@@ -154,9 +154,13 @@ class CNNModel:
         return (nll * weights).sum() / jnp.maximum(weights.sum(), 1e-6)
 
     def loss_fn(self, params, batch):
-        cut = self.client_forward(params, batch)
-        logits = self.server_forward(params["server"], cut)
-        loss = self._xent(logits, batch)
+        # the scopes name the split's halves in the HLO metadata; the
+        # backward carries them as transpose(jvp(...))
+        with jax.named_scope("psl.client"):
+            cut = self.client_forward(params, batch)
+        with jax.named_scope("psl.server"):
+            logits = self.server_forward(params["server"], cut)
+            loss = self._xent(logits, batch)
         acc = ((logits.argmax(-1) == batch["labels"]) * batch["weights"]
                ).sum() / jnp.maximum(batch["weights"].sum(), 1e-6)
         return loss, {"loss": loss, "accuracy": acc,

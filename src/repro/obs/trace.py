@@ -24,6 +24,13 @@ simulated trace is a deterministic function of the spec. Export targets:
   seconds-domain timestamps, the machine-readable twin the monitors and
   ``tools/trace_report.py`` consume.
 
+With ``annotate=True`` every span also enters a
+``jax.profiler.TraceAnnotation`` named ``"repro." + name`` with the span's
+args, so that under a ``jax.profiler`` trace it lands on the profiler's
+host plane, on the same clock as the device's ops and programs.
+:func:`tracer_from_spec` turns it on when the spec names a profiler
+directory and the clock is the wall clock.
+
 Disabled runs use the :class:`NullTracer`: every method is a no-op and
 ``span`` returns one shared reusable context manager, so the instrumented
 code paths cost one attribute lookup and an empty ``with`` block.
@@ -101,7 +108,7 @@ def null_tracer() -> NullTracer:
 class _SpanCM:
     """Context manager produced by :meth:`Tracer.span`."""
 
-    __slots__ = ("tracer", "name", "cat", "tid", "args", "_t0")
+    __slots__ = ("tracer", "name", "cat", "tid", "args", "_t0", "_ann")
 
     def __init__(self, tracer, name, cat, tid, args):
         self.tracer = tracer
@@ -109,13 +116,21 @@ class _SpanCM:
         self.cat = cat
         self.tid = tid
         self.args = args
+        self._ann = None
 
     def __enter__(self):
+        annotation = self.tracer._annotation
+        if annotation is not None:
+            self._ann = annotation("repro." + self.name, **self.args)
+            self._ann.__enter__()
         self._t0 = self.tracer.now()
         return self
 
     def __exit__(self, *exc):
-        self.tracer.complete(self.name, self._t0, self.tracer.now(),
+        t1 = self.tracer.now()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self.tracer.complete(self.name, self._t0, t1,
                              cat=self.cat, tid=self.tid, **self.args)
         return False
 
@@ -127,14 +142,22 @@ class Tracer:
     within one run): ``time.perf_counter`` (default), a scheduler
     ``WallClock.now``, or a ``VirtualClock.now`` for deterministic
     simulated traces. ``meta`` is attached to both export formats.
+    ``annotate`` mirrors every span into the profiler's trace as a
+    ``TraceAnnotation`` named ``"repro." + name`` (see the module doc).
     """
 
     enabled = True
 
     def __init__(self, clock: Optional[Callable[[], float]] = None,
-                 meta: Optional[Dict[str, Any]] = None):
+                 meta: Optional[Dict[str, Any]] = None,
+                 annotate: bool = False):
         self._clock = clock if clock is not None else time.perf_counter
         self.meta: Dict[str, Any] = dict(meta or {})
+        self.annotate = annotate
+        self._annotation = None
+        if annotate:
+            import jax
+            self._annotation = jax.profiler.TraceAnnotation
         self.events: List[Dict[str, Any]] = []    # chrome trace events
         self.records: List[Dict[str, Any]] = []   # JSONL-only records
 
@@ -242,11 +265,19 @@ class Tracer:
 
 
 def tracer_from_spec(obs_spec, clock: Optional[Callable[[], float]] = None,
-                     meta: Optional[Dict[str, Any]] = None):
-    """Tracer for an ``ObsSpec`` (None / disabled → the shared NullTracer)."""
+                     meta: Optional[Dict[str, Any]] = None,
+                     wall_clock: bool = True):
+    """Tracer for an ``ObsSpec`` (None / disabled → the shared NullTracer).
+
+    Its spans are annotated into the profiler's trace exactly when the
+    spec names ``jax_profiler_dir`` and ``wall_clock`` is true; a caller
+    that times spans on a simulated clock (the serving ``VirtualClock``)
+    passes ``wall_clock=False`` and keeps its trace off the profiler.
+    """
     if obs_spec is None or not obs_spec.enabled:
         return _NULL_TRACER
-    return Tracer(clock=clock, meta=meta)
+    return Tracer(clock=clock, meta=meta,
+                  annotate=bool(obs_spec.jax_profiler_dir) and wall_clock)
 
 
 def write_outputs(tracer, obs_spec) -> None:

@@ -15,6 +15,7 @@ Four guarantees, mirroring the layer's contract:
   primitives (P², percentiles with p99) agree with NumPy, and
   tools/trace_report.py renders both export formats.
 """
+import dataclasses
 import json
 import pathlib
 import subprocess
@@ -26,6 +27,7 @@ import pytest
 pytest.importorskip("jax")
 
 from repro import api
+from repro.api.registry import get_protocol
 from repro.core import ClientPopulation, make_plan
 from repro.core.straggler import simulate_tpe, simulate_tpe_segments
 from repro.obs import (GPSLMonitor, Histogram, NullTracer, P2Quantile,
@@ -134,6 +136,109 @@ def test_tracer_spans_and_exports(tmp_path):
     tr.write_jsonl(q)
     lines = [json.loads(x) for x in q.read_text().splitlines()]
     assert lines == rows
+
+
+# ---------------------------------------------------------------------------
+# The training step's span tree, and spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _fit(tracer=None, max_steps=3, **obs):
+    """A few PSL steps of the small CNN through ``fit()``."""
+    spec = dataclasses.replace(
+        train_spec(**obs), execution=api.ExecutionSpec(max_steps=max_steps))
+    ctx = api.build_context(spec)
+    return api.fit(ctx, get_protocol("psl")(),
+                   api.default_callbacks(spec, ctx.data), tracer=tracer)
+
+
+def _inside(child, parent) -> bool:
+    return parent["ts"] <= child["ts"] \
+        and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
+
+
+def _host_annotations(profile_dir):
+    """``(name, stats)`` of every ``repro.*`` event the profiler wrote."""
+    from jax.profiler import ProfileData
+    files = list(pathlib.Path(profile_dir).rglob("*.xplane.pb"))
+    assert len(files) == 1
+    out = []
+    for plane in ProfileData.from_file(str(files[0])).planes:
+        for line in plane.lines:
+            out.extend((e.name, dict(e.stats)) for e in line.events
+                       if e.name.startswith("repro."))
+    return out
+
+
+def test_fit_span_tree_covers_each_step():
+    tr = Tracer()
+    res = _fit(tr, max_steps=3)
+    assert len(res.step_metrics) == 3
+    spans = {}
+    for e in tr.chrome_trace()["traceEvents"]:
+        spans.setdefault(e["name"], []).append(e)
+    for name in ("batch", "batch.draw", "batch.put", "step", "callbacks"):
+        assert len(spans[name]) == 3, name
+    assert [e["args"]["step"] for e in spans["step"]] == [0, 1, 2]
+    epoch, = spans["epoch"]
+    for t in range(3):
+        batch = spans["batch"][t]
+        assert _inside(spans["batch.draw"][t], batch)
+        assert _inside(spans["batch.put"][t], batch)
+        assert spans["batch.draw"][t]["ts"] <= spans["batch.put"][t]["ts"]
+        # batch, then step, then callbacks, in order and all in the epoch
+        order = [batch, spans["step"][t], spans["callbacks"][t]]
+        for a, b in zip(order, order[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"]
+        assert all(_inside(e, epoch) for e in order)
+
+
+def test_annotating_tracer_writes_spans_to_the_profiler(tmp_path):
+    prof = tmp_path / "prof"
+    _fit(max_steps=2, enabled=True, jax_profiler_dir=str(prof))
+    events = _host_annotations(prof)
+    names = {name for name, _ in events}
+    assert {"repro.run", "repro.epoch", "repro.plan", "repro.batch",
+            "repro.batch.draw", "repro.batch.put", "repro.step",
+            "repro.callbacks", "repro.eval"} <= names
+    steps = [stats for name, stats in events if name == "repro.step"]
+    assert sorted(s["step"] for s in steps) == [0, 1]
+    assert all(s["epoch"] == 0 for s in steps)
+
+
+def test_null_and_virtual_clock_tracers_write_no_annotations(tmp_path):
+    import jax
+    from repro.runtime.scheduler import VirtualClock, WallClock
+    prof = str(tmp_path / "prof")
+    obs = api.ObsSpec(enabled=True, jax_profiler_dir=prof)
+    virtual = tracer_from_spec(obs, clock=VirtualClock().now,
+                               wall_clock=False)
+    assert not virtual.annotate
+    assert tracer_from_spec(obs, clock=WallClock().now).annotate
+    assert not tracer_from_spec(api.ObsSpec(enabled=True)).annotate
+    with jax.profiler.trace(prof):
+        with null_tracer().span("null", step=0), virtual.span("virtual"):
+            pass
+        with tracer_from_spec(obs).span("wall", step=1):
+            pass
+    assert _host_annotations(prof) == [("repro.wall", {"step": 1})]
+    assert [e["name"] for e in virtual.chrome_trace()["traceEvents"]] \
+        == ["virtual"]
+
+
+def test_fused_step_hlo_names_the_psl_halves():
+    import jax
+    from repro.api.protocols import _fresh_state
+    from repro.core.psl import make_train_step
+    ctx = api.build_context(train_spec())
+    state = _fresh_state(ctx.model, ctx.optimizer, 0)
+    batch = api.batch_from(np.zeros((8, 16, 16, 3), np.float32),
+                           np.zeros(8, np.int64))
+    text = jax.jit(make_train_step(ctx.model, ctx.optimizer)).lower(
+        state, batch).as_text(debug_info=True)
+    for scope in ("jvp(psl.client)", "transpose(jvp(psl.client))",
+                  "jvp(psl.server)", "transpose(jvp(psl.server))",
+                  "psl.update"):
+        assert scope in text, scope
 
 
 # ---------------------------------------------------------------------------
@@ -294,8 +399,8 @@ def test_traced_training_bitwise_identical_and_artifacts(tmp_path):
     assert "gpsl_monitor" not in off.history.extras
     doc = json.loads(trace.read_text())
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"run", "epoch", "plan", "batch", "device_step", "eval"} <= names
-    steps = [e for e in doc["traceEvents"] if e["name"] == "device_step"]
+    assert {"run", "epoch", "plan", "batch", "step", "eval"} <= names
+    steps = [e for e in doc["traceEvents"] if e["name"] == "step"]
     assert len(steps) == len(on.step_metrics)
     rows = [json.loads(x) for x in events.read_text().splitlines()]
     assert rows[0]["kind"] == "meta" and rows[0]["meta"]["kind"] == "train"
@@ -309,6 +414,14 @@ def test_traced_training_bitwise_identical_and_artifacts(tmp_path):
 @pytest.fixture(scope="module")
 def serve_ctx():
     return api.build_serve_context(serve_spec())
+
+
+def test_virtual_clock_serving_writes_no_annotations(tmp_path, serve_ctx):
+    prof = tmp_path / "prof"
+    api.run_serve(serve_spec(enabled=True, jax_profiler_dir=str(prof)),
+                  ctx=serve_ctx)
+    assert not [name for name, _ in _host_annotations(prof)
+                if name.startswith("repro.")]
 
 
 @pytest.mark.slow

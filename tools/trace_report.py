@@ -7,7 +7,7 @@ structured JSONL):
 
 * run metadata (the tracer's ``meta``: train/serve, protocol/engine);
 * a **phase breakdown** — per span name: count, total time, and
-  mean/p50/p95/p99 durations (training: plan/batch/device_step/eval;
+  mean/p50/p95/p99 durations (training: plan/batch/step/callbacks/eval;
   serving: admit/decode_step/wait);
 * **request lifecycles** (serving traces) — per-phase
   enqueue/prefill/decode durations and end-to-end request latency,
