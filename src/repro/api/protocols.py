@@ -8,9 +8,9 @@ reproduce their trajectories seed-for-seed (tests/test_api.py proves the
 PSL path bitwise against a frozen copy of the pre-refactor loop).
 
 PSL consults the ExecutionSpec: engine "fused" jits the fused step on the
-default device; engine "sharded" (and every LM workload) lowers it through
-repro.launch.distributed.ShardedPSLEngine with per-shard batch placement
-and straggler arrival accounting.
+default device, with the state donated; engine "sharded" (and every LM
+workload) lowers it through repro.launch.distributed.ShardedPSLEngine with
+per-shard batch placement and straggler arrival accounting.
 """
 from __future__ import annotations
 
@@ -50,12 +50,21 @@ def _fresh_state(model, optimizer, seed: int) -> TrainState:
                       jnp.zeros((), jnp.int32))
 
 
+def _donating_step(ctx):
+    """The jitted fused step for a strategy that owns every buffer of the
+    state it passes in: the state is donated, so the new one is written
+    into its buffers instead of into fresh allocations, and the old
+    state's arrays are deleted by the call."""
+    return jax.jit(make_train_step(ctx.model, ctx.optimizer),
+                   donate_argnums=(0,))
+
+
 class _SingleStateStrategy(ProtocolStrategy):
     """Shared skeleton for protocols training one TrainState end to end."""
 
     def setup(self, ctx) -> Dict[str, Any]:
         return {"state": _fresh_state(ctx.model, ctx.optimizer, ctx.seed),
-                "step": jax.jit(make_train_step(ctx.model, ctx.optimizer)),
+                "step": _donating_step(ctx),
                 "rng": np.random.default_rng(ctx.seed)}
 
     def step(self, ctx, pstate, item: StepItem):
@@ -115,6 +124,7 @@ class FLStrategy(ProtocolStrategy):
             local_epochs = max(1, int(np.log2(k)) - 1)   # paper App. A
         params = ctx.model.init(jax.random.PRNGKey(ctx.seed))
         sizes = ctx.data.pop.dataset_sizes.astype(np.float64)
+        # not donated: each client's state starts from global_params
         return {"global_params": params,
                 "step": jax.jit(make_train_step(ctx.model, ctx.optimizer)),
                 "rng": np.random.default_rng(ctx.seed),
@@ -168,6 +178,8 @@ class SFLStrategy(ProtocolStrategy):
 
     def setup(self, ctx) -> Dict[str, Any]:
         sizes = ctx.data.pop.dataset_sizes.astype(np.float64)
+        # not donated: each client's state starts from params["client"]
+        # and the shared server segment
         return {"params": ctx.model.init(jax.random.PRNGKey(ctx.seed)),
                 "step": jax.jit(make_train_step(ctx.model, ctx.optimizer)),
                 "rng": np.random.default_rng(ctx.seed),
@@ -283,9 +295,8 @@ class PSLStrategy(ProtocolStrategy):
         if not self._sharded(ctx):
             return {"state": _fresh_state(ctx.model, ctx.optimizer,
                                           ctx.seed),
-                    "step": jax.jit(make_train_step(ctx.model,
-                                                    ctx.optimizer)),
-                    "engine": None}
+                    "step": _donating_step(ctx), "engine": None,
+                    "donated": None}
         from repro.launch.distributed import (ShardedPSLEngine,
                                               assign_clients_to_shards)
         engine = ShardedPSLEngine(
@@ -360,8 +371,13 @@ class PSLStrategy(ProtocolStrategy):
 
     def step(self, ctx, pstate, item: StepItem):
         if pstate["engine"] is None:
-            pstate["state"], metrics = pstate["step"](pstate["state"],
-                                                      item.batch)
+            old = pstate["state"]
+            pstate["state"], metrics = pstate["step"](old, item.batch)
+            if pstate["donated"] is None:       # once, at the first step
+                leaves = jax.tree_util.tree_leaves(old)
+                n = sum(x.is_deleted() for x in leaves)
+                pstate["donated"] = {"donated": n, "leaves": len(leaves)}
+                ctx.tracer.counter("psl.donated_state_leaves", n)
         else:
             pstate["state"], metrics = pstate["engine"].step(
                 pstate["state"], item.batch)
@@ -374,3 +390,5 @@ class PSLStrategy(ProtocolStrategy):
         engine = pstate.get("engine")
         if engine is not None:
             record.extras["sharding_fallbacks"] = engine.report.fallbacks
+        elif pstate["donated"] is not None:
+            record.extras["donated_state_leaves"] = pstate["donated"]

@@ -190,6 +190,11 @@ class ProtocolStrategy:
 
     and evaluates ``strategy.eval_params(ctx, pstate)`` on the epoch-end
     event.
+
+    A strategy may donate its state to its step (CL, SL and the fused
+    PSL step do), which deletes the state's arrays. So the params handed
+    to ``epoch_end`` and ``run_end`` callbacks are valid until the next
+    step: a callback that keeps them for later copies them first.
     """
 
     name: str = "?"

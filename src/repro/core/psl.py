@@ -173,7 +173,7 @@ def fused_grads(model, params, batch, microbatches: int = 1):
     return normalize_sum_grads(g_sum, m_sum, microbatches)
 
 
-def make_train_step(model, optimizer: Optimizer, donate: bool = True,
+def make_train_step(model, optimizer: Optimizer,
                     microbatches: int = 1) -> Callable:
     """Fused PSL optimization step: (state, batch) -> (state, metrics).
 

@@ -1,7 +1,9 @@
 """The declarative experiment API: spec round trips, the protocol registry,
 dotted overrides, and the seed-for-seed equivalence of ``api.run(spec)``
 against a frozen transcription of the pre-refactor ``train_psl`` loop."""
+import itertools
 import json
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -355,3 +357,88 @@ def test_run_with_straggler_spec_tracks_tpe():
     assert len(h.extras["tpe_ms"]) == 1
     assert h.extras["tpe_ms"][0] > 0
     assert h.extras["em_iterations"] > 0
+
+
+# ---------------------------------------------------------------------------
+# State ownership: who donates its TrainState to the step
+# ---------------------------------------------------------------------------
+
+def _leaves(tree):
+    return jax.tree_util.tree_leaves(tree)
+
+
+@pytest.mark.parametrize("name", ["psl", "cl", "sl"])
+def test_owned_state_is_donated_and_steps_stay_bitwise(name):
+    ctx = api.build_context(small_spec(name=name, epochs=1))
+    strategy = api.get_protocol(name)()
+    pstate = strategy.setup(ctx)
+    ref_state = jax.tree_util.tree_map(jnp.copy, pstate["state"])
+    ref_step = jax.jit(make_train_step(ctx.model, ctx.optimizer))
+    items = strategy.epoch_batches(ctx, pstate, strategy.plan_epoch(ctx, 0),
+                                   0)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        for item in itertools.islice(items, 3):
+            old = pstate["state"]
+            pstate, metrics = strategy.step(ctx, pstate, item)
+            ref_state, ref_metrics = ref_step(ref_state, item.batch)
+            assert all(x.is_deleted() for x in _leaves(old))
+            assert {k: float(v) for k, v in metrics.items()} \
+                == {k: float(v) for k, v in ref_metrics.items()}
+            for got, want in zip(_leaves(pstate["state"]),
+                                 _leaves(ref_state)):
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(want))
+    assert not [w for w in caught if "donated buffers" in str(w.message)]
+
+
+def test_psl_counts_its_donated_state_leaves_once():
+    from repro.obs import Tracer
+    spec = small_spec(epochs=1).replace(
+        execution=api.ExecutionSpec(max_steps=3))
+    ctx = api.build_context(spec)
+    tracer = Tracer()
+    result = api.fit(ctx, api.get_protocol("psl")(),
+                     api.default_callbacks(spec, ctx.data), tracer=tracer)
+    n = len(_leaves(result.state["state"]))
+    counts = [e["args"]["value"] for e in tracer.events
+              if e["ph"] == "C" and e["name"] == "psl.donated_state_leaves"]
+    assert counts == [n]
+    assert result.history.extras["donated_state_leaves"] \
+        == {"donated": n, "leaves": n}
+
+
+@pytest.mark.parametrize("name", ["fl", "sfl"])
+def test_per_client_strategies_keep_their_global_params(name):
+    ctx = api.build_context(small_spec(name=name, epochs=1))
+    strategy = api.get_protocol(name)()
+    pstate = strategy.setup(ctx)
+    key = "global_params" if name == "fl" else "params"
+    kept = pstate[key]
+    # a copy on the device: a host view of a CPU buffer would itself
+    # keep the buffer from being donated
+    kept_copy = jax.tree_util.tree_map(jnp.copy, kept)
+    ref_step = jax.jit(make_train_step(ctx.model, ctx.optimizer))
+    ref, client, switches = None, None, 0
+    for item in strategy.epoch_batches(ctx, pstate, None, 0):
+        if item.scope != client:
+            # FL starts each client from the global params; SFL from the
+            # global client segment and the server as the last client
+            # left it
+            start = kept
+            if name == "sfl" and ref is not None:
+                start = {"client": kept["client"],
+                         "server": ref.params["server"]}
+            ref = TrainState(start, ctx.optimizer.init(start),
+                             jnp.zeros((), jnp.int32))
+            client, switches = item.scope, switches + 1
+        pstate, metrics = strategy.step(ctx, pstate, item)
+        ref, ref_metrics = ref_step(ref, item.batch)
+        assert float(metrics["loss"]) == float(ref_metrics["loss"])
+        assert not any(x.is_deleted() for x in _leaves(kept))
+    assert switches == ctx.data.store.num_clients
+    pstate = strategy.end_epoch(ctx, pstate, 0)
+    for got, want in zip(_leaves(kept), _leaves(kept_copy)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert all(np.isfinite(np.asarray(x)).all()
+               for x in _leaves(strategy.eval_params(ctx, pstate)))

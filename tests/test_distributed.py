@@ -83,9 +83,8 @@ def test_microbatch_accumulation_equals_single_pass(ragged):
 def test_microbatched_train_step_matches_plain_step():
     model = _model()
     opt = optim.sgd(0.05, momentum=0.9)
-    step1 = jax.jit(make_train_step(model, opt, donate=False))
-    step4 = jax.jit(make_train_step(model, opt, donate=False,
-                                    microbatches=4))
+    step1 = jax.jit(make_train_step(model, opt))
+    step4 = jax.jit(make_train_step(model, opt, microbatches=4))
     params = model.init(jax.random.PRNGKey(0))
     s1 = TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
     s4 = TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
@@ -233,7 +232,7 @@ def maxdiff(a, b):
 
 # single-device baseline (default device; mesh untouched)
 params = model.init(jax.random.PRNGKey(0))
-step = jax.jit(make_train_step(model, opt, donate=False))
+step = jax.jit(make_train_step(model, opt))
 st0 = TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
 for t in range(STEPS):
     st0, _ = step(st0, {k: jnp.asarray(v) for k, v in mkbatch(t).items()})
