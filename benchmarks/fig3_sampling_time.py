@@ -8,15 +8,15 @@ Three claims are measured:
 2. Planner-engine scaling (the repo's extension): the jit-compiled JAX
    planner (``backend="jax"``, src/repro/core/planner.py) against the NumPy
    reference across a K-sweep up to 65536 clients. The ``speedup_x`` derived
-   field is the acceptance gate: the engine is ≥10× faster at K ≥ 16384
-   (the LDS cells, where planning is dominated by the on-device MAP-EM
-   replanning, clear 10× with margin; UGS cells are bounded by the dense
-   (T, K) plan materialization that both backends share and show the
-   crossover curve).
+   field is the ratio on the host that runs it, not evidence of speed on
+   the chip (LDS cells are dominated by the on-device MAP-EM replanning;
+   UGS cells are bounded by the dense (T, K) plan materialization that
+   both backends share).
 3. Million-client sparse planning (``plan_format="sparse"``): a K-sweep
-   through 1e6 clients with plan-bytes and peak-RSS columns, written to
-   BENCH_plan.json. Sparse plans store per-step active-client segments —
-   O(T·B) memory — so plan bytes per client *fall* as K grows past B.
+   through 1e6 clients with plan-bytes and peak-RSS columns, written as
+   JSON to ``--out`` when given. Sparse plans store per-step active-client
+   segments — O(T·B) memory — so plan bytes per client *fall* as K grows
+   past B.
 
 Timing convention (audited): every jit-backed cell pays its one-time
 compile in an untimed warmup call and reports the best of N steady-state
@@ -101,7 +101,7 @@ _SPARSE_SWEEP = {
 
 
 def sparse_sweep(csv: Csv, ks, jax_repeat: int = 2):
-    """Sparse-format K-sweep; returns the BENCH_plan.json cell records."""
+    """Sparse-format K-sweep; returns the cell records ``--out`` writes."""
     cells = []
     for k in ks:
         lo, hi, b = _SPARSE_SWEEP[k]
@@ -161,7 +161,7 @@ def run(csv: Csv, quick: bool = False):
     # backends materialize the dense (T, K) plan, which bounds the UGS
     # ratio; reported for the scaling curve. The 65536 cells live in
     # --full: their dense (T, K) plans run to gigabytes, too heavy for the
-    # CI-sized quick pass (the >=10x gate is the quick LDS K=16384 cell).
+    # CI-sized quick pass.
     ugs_ks = [1024, 8192, 16384] if quick else [1024, 8192, 32768, 65536]
     for k in ugs_ks:
         pop = _sweep_pop(k, per=16, seed=k)
@@ -170,8 +170,7 @@ def run(csv: Csv, quick: bool = False):
                     lambda s: ugs_plan(pop, 128, seed=s, backend="jax"))
 
     # LDS: B = 256; planning cost is dominated by the MAP-EM re-estimation
-    # after every RemoveComponent, which the engine keeps on-device — this
-    # is where the >=10x acceptance bar is cleared at K >= 16384.
+    # after every RemoveComponent, which the engine keeps on-device.
     lds_ks = [4096, 16384] if quick else [4096, 16384, 65536]
     for k in lds_ks:
         pop = _sweep_pop(k, per=20, seed=k + 1)
@@ -180,20 +179,18 @@ def run(csv: Csv, quick: bool = False):
                     lambda s: lds_plan(pop, 256, seed=s, backend="jax"))
 
     # ---- sparse-format scaling (summary cells; the full K = 1e6 sweep
-    # with the BENCH_plan.json artifact runs via this module's main) ------
+    # runs via this module's main) -----------------------------------------
     sparse_sweep(csv, [4096] if quick else [4096, 65536])
 
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
-                    help="CI: small-K cells only, no artifact rewrite "
-                         "unless --out is given explicitly")
+                    help="CI: small-K cells only")
     ap.add_argument("--full", action="store_true",
                     help="paper-fidelity grids + the K = 1e6 sparse sweep")
     ap.add_argument("--out", default=None,
-                    help="write the sparse-sweep JSON artifact here "
-                         f"(default on --full: {ROOT / 'BENCH_plan.json'})")
+                    help="write the sparse-sweep JSON artifact here")
     args = ap.parse_args()
 
     csv = Csv()
@@ -206,10 +203,7 @@ def main() -> None:
     if not args.smoke:
         run(csv, quick=not args.full)
 
-    out = args.out
-    if out is None and not args.smoke:
-        out = str(ROOT / "BENCH_plan.json")
-    if out:
+    if args.out:
         result = {
             "bench": "fig3_plan_scaling",
             "timing": "best-of-N steady state; jit compile excluded by an "
@@ -220,8 +214,9 @@ def main() -> None:
                     "process high-water mark (monotone across cells).",
             "sweeps": cells,
         }
-        pathlib.Path(out).write_text(json.dumps(result, indent=2) + "\n")
-        print(f"wrote {out}")
+        pathlib.Path(args.out).write_text(json.dumps(result, indent=2)
+                                          + "\n")
+        print(f"wrote {args.out}")
 
 
 if __name__ == "__main__":

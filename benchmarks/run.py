@@ -1,13 +1,9 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived``
 # CSV. ``--full`` runs the paper-fidelity grids; default is the quick pass
-# (same claims, smaller grids) suitable for CI. ``--gate`` skips the CSV
-# suites and instead regenerates the named benches, diffing them against
-# the committed BENCH_*.json baselines (benchmarks/regression.py) — exit 1
-# on any out-of-band metric.
+# (same claims, smaller grids) suitable for CI.
 from __future__ import annotations
 
 import argparse
-import pathlib
 import sys
 import traceback
 
@@ -20,34 +16,12 @@ def main() -> None:
                     help="paper-fidelity grids (slow)")
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of benchmark names")
-    ap.add_argument("--gate", default=None, metavar="BENCHES",
-                    help="perf-regression gate: comma-separated subset of "
-                         "serve,train,plan to regenerate and diff against "
-                         "the committed BENCH_*.json baselines")
-    ap.add_argument("--gate-best-of", type=int, default=2,
-                    help="regenerations per gated bench (best-of merge)")
-    ap.add_argument("--baseline-dir", default=None,
-                    help="directory holding the BENCH_*.json baselines "
-                         "(default: repo root)")
-    ap.add_argument("--tol-scale", type=float, default=1.0,
-                    help="widen every gate tolerance band by this factor")
     args, _ = ap.parse_known_args()
     quick = not args.full
 
-    if args.gate:
-        from benchmarks import regression
-        base_dir = (pathlib.Path(args.baseline_dir)
-                    if args.baseline_dir else regression.ROOT)
-        ok = regression.run_gate(
-            [b.strip() for b in args.gate.split(",") if b.strip()],
-            baseline_dir=base_dir, best_of=args.gate_best_of,
-            tol_scale=args.tol_scale)
-        raise SystemExit(0 if ok else 1)
-
     from benchmarks import (fig2_em_iters, fig3_sampling_time,
                             fig6_deviation, fig7_deviation_lds,
-                            kernels_micro, roofline, table2_accuracy,
-                            table3_lds_accuracy, table4_tpe)
+                            table2_accuracy, table3_lds_accuracy, table4_tpe)
     suites = {
         "fig6_deviation": fig6_deviation.run,
         "fig7_deviation_lds": fig7_deviation_lds.run,
@@ -56,8 +30,6 @@ def main() -> None:
         "fig3_sampling_time": fig3_sampling_time.run,
         "table2_accuracy": table2_accuracy.run,
         "table3_lds_accuracy": table3_lds_accuracy.run,
-        "kernels_micro": kernels_micro.run,
-        "roofline": roofline.run,
     }
     if args.only:
         keep = set(args.only.split(","))

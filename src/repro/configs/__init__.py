@@ -2,17 +2,12 @@
 
 ``get_config(arch_id)`` returns the exact assigned configuration;
 ``get_config(arch_id, reduced=True)`` returns the smoke-test variant
-(≤2-ish layers, d_model ≤ 512, ≤4 experts). ``shape_adapted`` applies
-per-input-shape config adjustments (sliding window for long-context decode
-on attention archs).
+(≤2-ish layers, d_model ≤ 512, ≤4 experts).
 """
 from __future__ import annotations
 
-import dataclasses
 import importlib
 from typing import Dict, List
-
-from repro.models.config import INPUT_SHAPES, ModelConfig, ShapeConfig
 
 _MODULES: Dict[str, str] = {
     "granite-3-2b": "granite_3_2b",
@@ -30,29 +25,8 @@ _MODULES: Dict[str, str] = {
 
 ARCH_IDS: List[str] = [k for k in _MODULES if k != "paper-cnn"]
 
-# Documented skips (DESIGN.md §Arch-applicability): (arch, shape) pairs that
-# are architecturally meaningless and therefore not lowered.
-SKIPS = {("whisper-tiny", "long_500k"):
-         "448-position learned decoder embedding + 1500-frame encoder; "
-         "a 524k-token decode contradicts the architecture"}
-
-# Window applied to attention-bearing archs for the long-context decode
-# shape (the sub-quadratic carve-out; SSM/hybrid run natively).
-LONG_CONTEXT_WINDOW = 8192
-
 
 def get_config(arch_id: str, reduced: bool = False):
     mod = importlib.import_module(f"repro.configs.{_MODULES[arch_id]}")
     return mod.REDUCED if reduced else mod.CONFIG
 
-
-def shape_adapted(cfg: ModelConfig, shape: ShapeConfig) -> ModelConfig:
-    """Per-shape config adjustments."""
-    if (shape.name == "long_500k" and cfg.family in
-            ("dense", "moe", "vlm") and cfg.sliding_window is None):
-        return dataclasses.replace(cfg, sliding_window=LONG_CONTEXT_WINDOW)
-    return cfg
-
-
-def is_skipped(arch_id: str, shape_name: str):
-    return SKIPS.get((arch_id, shape_name))

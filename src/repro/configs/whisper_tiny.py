@@ -2,11 +2,8 @@
 the assignment (encoder consumes precomputed frame embeddings, 1500 frames).
 [arXiv:2212.04356]
 
-Shape notes (DESIGN.md §Arch-applicability): decode shapes run the decoder
-with a KV cache; ``long_500k`` is SKIPPED — whisper's decoder has a learned
-448-position embedding and a 1500-frame encoder, so a 524k-token decode
-contradicts the architecture. ``max_seq_len`` is enlarged to 32768 so
-``decode_32k`` exercises the serving path mechanically.
+Decode runs the decoder with a KV cache. ``max_seq_len`` is enlarged to
+32768 so a 32k-token decode exercises the serving path mechanically.
 """
 from repro.models.config import ModelConfig
 

@@ -1,30 +1,17 @@
-"""Mesh factories for the production TPU v5e topology.
+"""Mesh factories for the sharded training engine.
 
-Nothing at module scope touches jax device state; the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before importing
-jax so ``make_production_mesh`` can build the full pod meshes on the CPU
-container.
+Nothing at module scope touches jax device state, so a caller can force
+host devices (``XLA_FLAGS=--xla_force_host_platform_device_count=N``)
+before jax starts.
 """
 from __future__ import annotations
 
 import jax
 
-# TPU v5e hardware constants used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12          # per chip, FLOP/s
-HBM_BW = 819e9                    # per chip, B/s
-ICI_BW = 50e9                     # per link, B/s
-
 
 def _make_mesh(shape, axes):
     return jax.make_mesh(shape, axes,
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):

@@ -5,7 +5,8 @@ A :class:`ModelConfig` fully describes one architecture; builders in
 logical axes, initializer). The same spec tree serves three purposes:
 
   * ``init``        — materialize random parameters (smoke tests, examples);
-  * ``abstract``    — ``jax.ShapeDtypeStruct`` stand-ins (multi-pod dry-run);
+  * ``abstract``    — ``jax.ShapeDtypeStruct`` stand-ins (shardings of the
+                      optimizer state, without allocating);
   * ``shardings``   — logical axes → ``PartitionSpec`` via the rules in
                       :mod:`repro.sharding`.
 """
@@ -13,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -86,7 +87,6 @@ class ModelConfig:
     attn_q_chunk: int = 512
     attn_kv_chunk: int = 512
     causal_block_skip: bool = True         # skip fully-masked kv blocks
-    scan_layers: bool = True
 
     # provenance
     source: str = ""
@@ -195,23 +195,3 @@ class ParamSpec:
         if len(self.shape) != len(self.axes):
             raise ValueError(f"axes {self.axes} do not match shape {self.shape}")
 
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    """One of the assigned input-shape workloads."""
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str            # "train" | "prefill" | "decode"
-
-    @property
-    def is_decode(self) -> bool:
-        return self.kind == "decode"
-
-
-INPUT_SHAPES: Dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}

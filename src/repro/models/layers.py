@@ -3,7 +3,7 @@
 
 Everything is a pure function over explicit parameter pytrees; parameters are
 declared via :class:`ParamSpec` trees so the same definitions drive random
-init, abstract (dry-run) init, and sharding-spec derivation.
+init, abstract (shape-only) init, and sharding-spec derivation.
 """
 from __future__ import annotations
 
@@ -74,7 +74,7 @@ def materialize(spec_tree, key, dtype) -> Any:
 
 
 def abstractify(spec_tree, dtype) -> Any:
-    """ShapeDtypeStruct stand-ins — no allocation (dry-run path)."""
+    """ShapeDtypeStruct stand-ins — no allocation."""
     def one(spec: ParamSpec):
         return jax.ShapeDtypeStruct(spec.shape, spec.dtype or dtype)
     return tree_map_specs(one, spec_tree)

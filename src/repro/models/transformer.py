@@ -42,34 +42,10 @@ def _remat(fn, mode: str):
     return fn
 
 
-def _stack_len(stacked) -> int:
-    return jax.tree_util.tree_leaves(stacked)[0].shape[0]
-
-
-def _layer_slice(stacked, i: int):
-    return jax.tree_util.tree_map(lambda x: x[i], stacked)
-
-
-def scan_stack(cfg, body, carry, *stacked):
-    """lax.scan over stacked layer params — or an unrolled python loop when
-    ``cfg.scan_layers`` is False (the dry-run accounting mode: XLA's
-    cost_analysis counts a while-loop body once, so roofline numbers are
-    derived from the unrolled lowering; training uses the scanned form for
-    compile-time sanity)."""
-    if cfg.scan_layers:
-        return jax.lax.scan(body, carry, stacked if len(stacked) > 1
-                            else stacked[0])
-    n = _stack_len(stacked[0])
-    ys = []
-    for i in range(n):
-        sliced = tuple(_layer_slice(s, i) for s in stacked)
-        carry, y = body(carry, sliced if len(stacked) > 1 else sliced[0])
-        ys.append(y)
-    if ys and ys[0] is not None:
-        ys = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ys)
-    else:
-        ys = None
-    return carry, ys
+def scan_stack(body, carry, *stacked):
+    """lax.scan over stacked layer params."""
+    return jax.lax.scan(body, carry, stacked if len(stacked) > 1
+                        else stacked[0])
 
 
 # ---------------------------------------------------------------------------
@@ -430,7 +406,7 @@ class LanguageModel:
                                                 window=window)
                 return (xx, aa), None
         body = _remat(body, cfg.remat)
-        (x, aux), _ = scan_stack(cfg, body, (x, aux), stacked)
+        (x, aux), _ = scan_stack(body, (x, aux), stacked)
         return x, aux
 
     def _shared_attn_apply(self, p, x, positions, window):
@@ -457,7 +433,7 @@ class LanguageModel:
                                              positions, window)
                 xx, aa = self._run_stack(lp, xx, aa, positions, window)
                 return (xx, aa), None
-            (x, aux), _ = scan_stack(cfg, super_body, (x, aux),
+            (x, aux), _ = scan_stack(super_body, (x, aux),
                                      srv["superblocks"])
         else:
             x, aux = self._run_stack(srv["blocks"], x, aux, positions, window)
@@ -523,7 +499,7 @@ class LanguageModel:
                                              xx, positions, window)
                 xx, aa = self._run_stack(lp, xx, aa, positions, window)
                 return (xx, aa), None
-            (x, aux), _ = scan_stack(cfg, super_body, (x, aux),
+            (x, aux), _ = scan_stack(super_body, (x, aux),
                                      server_params["superblocks"])
         else:
             x, aux = self._run_stack(server_params["blocks"], x, aux,
@@ -569,10 +545,8 @@ class LanguageModel:
                                       cfg.num_layers - cfg.cut_layer)}
 
     def init_cache(self, batch: int, cache_len: int,
-                   window: Optional[int] = None, abstract: bool = False):
+                   window: Optional[int] = None):
         specs = self.cache_specs(batch, cache_len, window)
-        if abstract:
-            return L.abstractify(specs, self.cfg.jnp_dtype)
         return L.tree_map_specs(
             lambda s: jnp.zeros(s.shape, s.dtype or self.cfg.jnp_dtype),
             specs)
@@ -599,7 +573,7 @@ class LanguageModel:
             def body(xx, lp):
                 xx, st = self.blocks.ssm_block_prefill(lp, xx)
                 return xx, st
-            x, states = scan_stack(cfg, body, x, stacked)
+            x, states = scan_stack(body, x, stacked)
             return x, states
 
         def body(xx, lp):
@@ -608,7 +582,7 @@ class LanguageModel:
                 lp, xx, positions, aux, window=window, fill_cache=True)
             return xx, {"k": self._to_ring(kc, cache_len),
                         "v": self._to_ring(vc, cache_len)}
-        x, caches = scan_stack(cfg, body, x, stacked)
+        x, caches = scan_stack(body, x, stacked)
         return x, caches
 
     def prefill(self, params, batch, cache_len: Optional[int] = None,
@@ -653,7 +627,7 @@ class LanguageModel:
                 return xx, (attn_cache, ssm_states)
 
             x, (attn_caches, super_states) = scan_stack(
-                cfg, super_body, x, srv["superblocks"])
+                super_body, x, srv["superblocks"])
             cache["server_attn"] = attn_caches
             cache["server_super"] = super_states
         else:
@@ -676,7 +650,7 @@ class LanguageModel:
                 xx, nc = self.blocks.attn_block_decode(lp, xx, lc, pos,
                                                        window=window)
                 return xx, nc
-        x, new_cache = scan_stack(cfg, body, x, stacked_params,
+        x, new_cache = scan_stack(body, x, stacked_params,
                                   stacked_cache)
         return x, new_cache
 
@@ -719,8 +693,8 @@ class LanguageModel:
                 return xx, ({"k": kc, "v": vc}, new_ssm)
 
             x, (new_attn, new_super) = scan_stack(
-                cfg, super_body, x, srv["superblocks"],
-                cache["server_attn"], cache["server_super"])
+                super_body, x, srv["superblocks"], cache["server_attn"],
+                cache["server_super"])
             new_cache["server_attn"] = new_attn
             new_cache["server_super"] = new_super
         else:
@@ -737,7 +711,7 @@ class LanguageModel:
             xx, nc = self.blocks.attn_block_decode_paged(lp, xx, lc, pos,
                                                          page_table)
             return xx, nc
-        return scan_stack(self.cfg, body, x, stacked_params, stacked_cache)
+        return scan_stack(body, x, stacked_params, stacked_cache)
 
     def decode_step_paged(self, params, cache, tokens, pos, page_table):
         """One-token decode over a paged KV cache. tokens: (B, 1) int32;
@@ -775,7 +749,7 @@ class LanguageModel:
             xx, nc = self.blocks.attn_block_decode_window_paged(
                 lp, xx, lc, q_pos, page_table)
             return xx, nc
-        return scan_stack(self.cfg, body, x, stacked_params, stacked_cache)
+        return scan_stack(body, x, stacked_params, stacked_cache)
 
     def decode_window_paged(self, params, cache, tokens, q_pos, page_table):
         """W-token speculative-verify decode over a paged KV cache.
@@ -884,7 +858,7 @@ class EncDecModel:
             return xx + L.mlp_apply(lp["mlp"], hn2, gelu=True), None
 
         body = _remat(body, cfg.remat)
-        x, _ = scan_stack(cfg, body, x, c["enc_blocks"])
+        x, _ = scan_stack(body, x, c["enc_blocks"])
         return L.rms_norm(x, c["enc_norm"], cfg.norm_eps)
 
     def _decoder(self, server_params, enc, tokens, pos_offset=0,
@@ -944,13 +918,13 @@ class EncDecModel:
 
         if cache is None:
             if fill_len is not None:
-                x, new_cache = scan_stack(cfg, body, x, s["dec_blocks"])
+                x, new_cache = scan_stack(body, x, s["dec_blocks"])
             else:
                 bodyr = _remat(lambda c, i: body(c, i), cfg.remat)
-                x, _ = scan_stack(cfg, bodyr, x, s["dec_blocks"])
+                x, _ = scan_stack(bodyr, x, s["dec_blocks"])
                 new_cache = None
         else:
-            x, new_cache = scan_stack(cfg, body, x, s["dec_blocks"],
+            x, new_cache = scan_stack(body, x, s["dec_blocks"],
                                       cache)
         x = L.rms_norm(x, s["final_norm"], cfg.norm_eps)
         return x, new_cache
@@ -980,11 +954,8 @@ class EncDecModel:
                 "enc": ParamSpec((batch, cfg.encoder_seq, cfg.d_model),
                                  ("batch", None, "embed"), init="zeros")}
 
-    def init_cache(self, batch: int, cache_len: int, window=None,
-                   abstract: bool = False):
+    def init_cache(self, batch: int, cache_len: int, window=None):
         specs = self.cache_specs(batch, cache_len, window)
-        if abstract:
-            return L.abstractify(specs, self.cfg.jnp_dtype)
         return L.tree_map_specs(
             lambda s: jnp.zeros(s.shape, s.dtype or self.cfg.jnp_dtype),
             specs)

@@ -10,7 +10,7 @@ each, every transformer layer's K and V for those positions), a request
 holds a **page table** (ordered list of physical page ids), and pages are
 allocated one at a time exactly when decode advances into them. Peak
 memory then tracks the sum of *live context lengths*, rounded up to a
-page — the heavy-tail workload win measured in BENCH_serve.json.
+page — the win on heavy-tailed completion lengths.
 
 Admission keeps the GPSL fixed-work invariant, restated in pages: admit
 while the free list can cover the candidate's prompt **plus one growth

@@ -12,7 +12,8 @@ multi-pod. PSL semantics drive two rule sets:
 Every ``ParamSpec`` dimension carries a logical axis name; ``spec_for``
 resolves it to mesh axes with a divisibility check — a dimension that does
 not divide the assigned mesh axes falls back to replication and the fallback
-is recorded (surfaced in the dry-run report instead of failing the lowering).
+is recorded (surfaced in the ``ShardingReport`` instead of failing the
+lowering).
 """
 from __future__ import annotations
 
@@ -184,28 +185,23 @@ def train_state_shardings(model, optimizer, mesh: Mesh,
 # --------------------------------------------------------------------------
 # Activation sharding constraints (§Perf: GSPMD needs explicit hints to keep
 # residual-stream activations sharded under ddp / sequence-parallel layouts;
-# without them it replicates over idle axes — measured in EXPERIMENTS.md).
-# Set by the launcher before tracing; consulted by the transformer blocks.
+# without them it replicates over idle axes). Set around tracing by
+# ``ShardedPSLEngine._constrained``; consulted by the transformer blocks.
 # --------------------------------------------------------------------------
 
 _ACTIVATION_SHARDING: Optional[NamedSharding] = None
-
-
-def set_activation_sharding(ns: Optional[NamedSharding]) -> None:
-    global _ACTIVATION_SHARDING
-    _ACTIVATION_SHARDING = ns
 
 
 @contextlib.contextmanager
 def activation_sharding(ns: Optional[NamedSharding]):
     """Set the activation constraint for one block (e.g. one trace) and
     restore the previous one after it."""
-    prev = _ACTIVATION_SHARDING
-    set_activation_sharding(ns)
+    global _ACTIVATION_SHARDING
+    prev, _ACTIVATION_SHARDING = _ACTIVATION_SHARDING, ns
     try:
         yield
     finally:
-        set_activation_sharding(prev)
+        _ACTIVATION_SHARDING = prev
 
 
 def constrain_activation(x):
@@ -216,17 +212,12 @@ def constrain_activation(x):
 
 
 def activation_sharding_for(mesh: Mesh, layout: str) -> NamedSharding:
-    """layout: 'batch' → (B over all axes, S, d) [ddp]; 'seq' → (B over data,
-    S over model, d) [Megatron-style sequence parallelism]."""
-    data = _data_axes(mesh)
-    if layout == "batch":
-        axes = data + ("model",)
-        return NamedSharding(mesh, PartitionSpec(
-            axes if len(axes) > 1 else axes[0], None, None))
-    if layout == "seq":
-        return NamedSharding(mesh, PartitionSpec(
-            data if len(data) > 1 else data[0], "model", None))
-    raise ValueError(layout)
+    """layout: 'batch' → (B over all axes, S, d) [fsdp / ddp]."""
+    if layout != "batch":
+        raise ValueError(layout)
+    axes = _data_axes(mesh) + ("model",)
+    return NamedSharding(mesh, PartitionSpec(
+        axes if len(axes) > 1 else axes[0], None, None))
 
 
 def batch_axes(mesh: Mesh, profile: str = "tp") -> Tuple[str, ...]:
@@ -246,7 +237,7 @@ def batch_shardings(batch_tree, mesh: Mesh, global_batch: int,
                     report: Optional[ShardingReport] = None,
                     profile: str = "tp"):
     """Shard dim 0 (batch) of every batch leaf over the data axes, falling
-    back to replication when the batch does not divide (long_500k, B=1)."""
+    back to replication when the batch does not divide."""
     axes = batch_axes(mesh, profile)
     total = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
 
@@ -261,13 +252,3 @@ def batch_shardings(batch_tree, mesh: Mesh, global_batch: int,
 
     return jax.tree_util.tree_map(one, batch_tree)
 
-
-def cache_shardings(model, mesh: Mesh, batch: int, cache_len: int,
-                    window=None,
-                    report: Optional[ShardingReport] = None,
-                    profile: str = "tp"):
-    """KV/SSM decode-cache shardings from the cache ParamSpec tree: batch dim
-    over the data axes, cache head / inner dims over `model`."""
-    specs = model.cache_specs(batch, cache_len, window)
-    return shardings_for_specs(specs, mesh, server_rules(mesh, profile),
-                               report)

@@ -8,11 +8,14 @@ Three layers of evidence:
      reproduces the single-pass backward and the decomposed six-substep
      protocol; slot-weight invariants hold for any batch composition.
   2. subprocess (4 forced host devices — XLA locks the device count at
-     first jax init, the test_dryrun.py pattern): both lowerings (gspmd
-     profile shardings and explicit shard_map data parallelism) produce
+     first jax init, so the mesh tests run in a child process): both
+     lowerings (gspmd profile shardings and explicit shard_map data
+     parallelism) produce
      gradients equal to the single-device fused step and to
      ``decomposed_grads``, and multi-step training trajectories stay
      identical within fp tolerance. Microbatching composes with the mesh.
+     A second subprocess (16 devices, a 4x4 mesh) compiles and steps the
+     engine for the reduced transformer families under every profile.
   3. the distributed straggler accounting (per-shard arrivals) is
      consistent with the single-server TPE model.
 """
@@ -276,3 +279,95 @@ def test_sharded_step_equivalence_4way_host_mesh():
             assert val == [], (key, val)
         else:
             assert val < 1e-4, (key, val)
+
+
+# ------------------------------------ 4x4 host mesh: every family, profile
+
+_ARCHS = ("granite-3-2b", "granite-moe-3b-a800m", "falcon-mamba-7b",
+          "zamba2-2.7b", "whisper-tiny")
+_PROFILES = ("tp", "fsdp", "ddp")
+# batch shards on a 4x4 (data x model) mesh: fsdp also splits over model
+_SHARDS = {"tp": 4, "fsdp": 16, "ddp": 4}
+
+# Each device runs its program on its own thread (no shared intra-op pool,
+# where collective thunks blocked on a loaded host starve the other
+# participants of their rendezvous), and CPU codegen runs at its lowest
+# level, which halves compile time and leaves the partitioned program as
+# it is. One process per architecture, run side by side.
+_SCRIPT_4X4 = r"""
+import os
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=16 "
+                           "--xla_cpu_multi_thread_eigen=false "
+                           "--xla_backend_optimization_level=0 "
+                           "--xla_llvm_disable_expensive_passes=true")
+import json, sys
+import numpy as np, jax
+from repro import optim
+from repro.configs import get_config
+from repro.models import build_model
+from repro.launch.mesh import make_training_mesh
+from repro.launch.distributed import ShardedPSLEngine, data_shard_count
+
+B, S = 16, 32
+mesh = make_training_mesh("4x4")
+out = {}
+for arch in sys.argv[1].split(","):
+    cfg = get_config(arch, reduced=True)
+    model = build_model(cfg)
+    r = np.random.default_rng(0)
+    batch = {"tokens": r.integers(0, cfg.vocab_size, (B, S)).astype(np.int32),
+             "labels": r.integers(0, cfg.vocab_size, (B, S)).astype(np.int32),
+             "weights": np.ones((B, S), np.float32)}
+    if cfg.family == "audio":
+        batch["frames"] = r.normal(size=(B, cfg.encoder_seq, cfg.d_model)
+                                   ).astype(cfg.jnp_dtype)
+    for profile in sys.argv[2].split(","):
+        try:
+            eng = ShardedPSLEngine(model, optim.adamw(1e-3), mesh, profile)
+            st, met = eng.step(eng.init_state(0), eng.put_batch(batch))
+            same = jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+                lambda x, s: x.sharding.is_equivalent_to(s, x.ndim),
+                st.params, eng.params_sh))
+            out[f"{arch}|{profile}"] = {
+                "loss": float(met["loss"]), "num_shards": eng.num_shards,
+                "want_shards": data_shard_count(mesh, profile),
+                "params_sh_kept": all(same)}
+        except Exception as e:  # noqa: BLE001 - reported per case
+            out[f"{arch}|{profile}"] = {"error": repr(e)[-2000:]}
+print("RESULTS_JSON:" + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def mesh_4x4_results():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    procs = [subprocess.Popen(
+        [sys.executable, "-c", _SCRIPT_4X4, arch, ",".join(_PROFILES)],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        for arch in _ARCHS]
+    results = {}
+    try:
+        for proc in procs:
+            out, err = proc.communicate(timeout=900)
+            assert proc.returncode == 0, err[-3000:]
+            line = [l for l in out.splitlines()
+                    if l.startswith("RESULTS_JSON:")][0]
+            results.update(json.loads(line[len("RESULTS_JSON:"):]))
+    finally:
+        for proc in procs:
+            proc.kill()
+    return results
+
+
+@pytest.mark.parametrize("profile", _PROFILES)
+@pytest.mark.parametrize("arch", _ARCHS)
+def test_sharded_step_on_4x4_host_mesh(mesh_4x4_results, arch, profile):
+    """The engine compiles and takes one finite step on a 4x4 mesh, splits
+    the batch into the profile's shard count, and hands back parameters
+    laid out as its own parameter shardings."""
+    r = mesh_4x4_results[f"{arch}|{profile}"]
+    assert "error" not in r, r["error"]
+    assert np.isfinite(r["loss"]), r
+    assert r["num_shards"] == r["want_shards"] == _SHARDS[profile]
+    assert r["params_sh_kept"]

@@ -1,4 +1,4 @@
-"""Sharding-rule resolution and HLO collective-parser unit tests.
+"""Sharding-rule resolution unit tests.
 
 These run on the single real CPU device: they construct a Mesh over one
 device but exercise the pure resolution logic with synthetic axis sizes via
@@ -12,7 +12,6 @@ import pytest
 from jax.sharding import PartitionSpec
 
 from repro import sharding as sh
-from repro.launch.hlo_analysis import Roofline, collective_bytes
 
 
 class FakeMesh:
@@ -75,49 +74,3 @@ def test_ddp_profile_no_layer_tp():
     assert r["vocab"] == ("model",)
     assert r["batch"] == ("data", "model")
 
-
-# ---------------------------------------------------------------- HLO parse
-
-_HLO = """
-HloModule test
-ENTRY main {
-  %p0 = f32[16,128]{1,0} parameter(0)
-  %ar = f32[16,128]{1,0} all-reduce(%p0), replica_groups={}
-  %ag = bf16[32,64]{1,0} all-gather(%p0), dimensions={0}
-  %rs = f32[8,128]{1,0} reduce-scatter(%ar), dimensions={0}
-  %a2a = f32[4,4]{1,0} all-to-all(%rs), dimensions={0}
-  %cp = u32[10]{0} collective-permute(%a2a)
-  %ars = f32[2,2]{1,0} all-reduce-start(%p0)
-  ROOT %ard = f32[2,2]{1,0} all-reduce-done(%ars)
-}
-"""
-
-
-def test_collective_bytes_parser():
-    out = collective_bytes(_HLO)
-    assert out["all-reduce"] == (16 * 128 * 4) * 2 + (2 * 2 * 4) * 2
-    assert out["all-gather"] == 32 * 64 * 2
-    assert out["reduce-scatter"] == 8 * 128 * 4
-    assert out["all-to-all"] == 4 * 4 * 4
-    assert out["collective-permute"] == 10 * 4
-    assert out["total"] == sum(out[k] for k in
-                               ("all-reduce", "all-gather", "reduce-scatter",
-                                "all-to-all", "collective-permute"))
-
-
-def test_collective_bytes_tuple_shapes():
-    hlo = ("%t = (f32[4,4]{1,0}, bf16[2,2]{1,0}) all-reduce(%a, %b), "
-           "replica_groups={}\n")
-    out = collective_bytes(hlo)
-    assert out["all-reduce"] == (4 * 4 * 4 + 2 * 2 * 2) * 2
-
-
-def test_roofline_terms_and_bottleneck():
-    r = Roofline(flops_per_device=197e12, hbm_bytes_per_device=819e9 * 2,
-                 collective_bytes_per_device=50e9 * 0.5, chips=256,
-                 peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9)
-    assert abs(r.compute_s - 1.0) < 1e-9
-    assert abs(r.memory_s - 2.0) < 1e-9
-    assert abs(r.collective_s - 0.5) < 1e-9
-    assert r.bottleneck == "memory"
-    assert r.step_time_s == r.memory_s
