@@ -296,7 +296,7 @@ class PSLStrategy(ProtocolStrategy):
             return {"state": _fresh_state(ctx.model, ctx.optimizer,
                                           ctx.seed),
                     "step": _donating_step(ctx), "engine": None,
-                    "donated": None}
+                    "donated": None, "client_pack": None}
         from repro.launch.distributed import (ShardedPSLEngine,
                                               assign_clients_to_shards)
         engine = ShardedPSLEngine(
@@ -378,6 +378,12 @@ class PSLStrategy(ProtocolStrategy):
                 n = sum(x.is_deleted() for x in leaves)
                 pstate["donated"] = {"donated": n, "leaves": len(leaves)}
                 ctx.tracer.counter("psl.donated_state_leaves", n)
+                pack = getattr(ctx.model, "client_pack", None)
+                if pack is not None:
+                    pstate["client_pack"] = pack(
+                        item.batch["images"].shape[0])
+                    ctx.tracer.counter("psl.client_pack",
+                                       pstate["client_pack"])
         else:
             pstate["state"], metrics = pstate["engine"].step(
                 pstate["state"], item.batch)
@@ -392,3 +398,5 @@ class PSLStrategy(ProtocolStrategy):
             record.extras["sharding_fallbacks"] = engine.report.fallbacks
         elif pstate["donated"] is not None:
             record.extras["donated_state_leaves"] = pstate["donated"]
+            if pstate["client_pack"] is not None:
+                record.extras["client_pack"] = pstate["client_pack"]

@@ -1,9 +1,17 @@
-"""Small GroupNorm ResNet for the paper-faithful PSL experiments.
+"""GroupNorm ResNet for the paper-faithful PSL experiments.
 
 The paper trains ResNet18 (BatchNorm → GroupNorm with 32 groups, cut after
-the third layer) on CIFAR10. We reproduce that setup at reduced scale on
-synthetic CIFAR-like data: a GN ResNet with the PSL cut after the stem+first
-stage, exposing the same client/server param split as the LMs.
+the third layer) on CIFAR10. We reproduce that setup on synthetic
+CIFAR-like data: a GN ResNet with the PSL cut after the stem+first stage,
+exposing the same client/server param split as the LMs. The benchmark runs
+it at ResNet-18's full widths (64/128/256/512, `configs/paper_cnn.py`
+CONFIG); tests and the quickstart run the reduced widths (REDUCED).
+
+The client segment packs `p` adjacent images side by side in the channel
+axis, with `p` the largest power of two that divides the batch and keeps
+`p` times the segment's widest channel count within the TPU's 128 lanes:
+block-diagonal convolution weights and `p`-fold GroupNorm groups keep
+the images apart, and the cut unpacks to the same (b, h, w, c) tensor.
 
 BatchNorm is deliberately NOT used: the paper replaces it because PSL's
 variable local batch sizes break batch statistics (App. A); GroupNorm is
@@ -49,6 +57,18 @@ def _gn_specs(c):
             "bias": ParamSpec((c,), (None,), init="zeros")}
 
 
+LANES = 128     # the minor (lane) width of a TPU vreg and HBM tile
+
+
+def group_count(c: int, groups: int) -> int:
+    """The GroupNorm group count `group_norm` takes for `c` channels: at
+    most `groups`, lowered until it divides `c`."""
+    g = min(groups, c)
+    while c % g:
+        g -= 1
+    return g
+
+
 def group_norm(x, p, groups: int, eps: float = 1e-5):
     """GroupNorm over `groups` groups of an NHWC activation, in float32.
 
@@ -58,9 +78,7 @@ def group_norm(x, p, groups: int, eps: float = 1e-5):
     channel. The variance is two-pass (mean first), as `jnp.var` takes it.
     """
     b, h, w, c = x.shape
-    g = min(groups, c)
-    while c % g:
-        g -= 1
+    g = group_count(c, groups)
     n = h * w * (c // g)
 
     def per_channel_group_mean(s):          # (b, c) sums -> (b, c) means
@@ -79,6 +97,38 @@ def conv(x, w, stride=1):
     return jax.lax.conv_general_dilated(
         x, w, (stride, stride), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+
+def block_diagonal(w, pack: int):
+    """(k, k, cin, cout) HWIO -> (k, k, pack*cin, pack*cout) with `pack`
+    copies of `w` on the diagonal and exact zeros elsewhere. A dense
+    kernel, not `feature_group_count`: XLA lays the grouped form out
+    with half-empty lanes."""
+    if pack == 1:
+        return w
+    k1, k2, cin, cout = w.shape
+    eye = jnp.eye(pack, dtype=w.dtype)
+    return jnp.einsum("ij,hwab->hwiajb", eye, w).reshape(
+        k1, k2, pack * cin, pack * cout)
+
+
+def pack_images(x, pack: int):
+    """(b, h, w, c) -> (b/pack, h, w, pack*c): adjacent images side by side
+    in the channel axis."""
+    if pack == 1:
+        return x
+    b, h, w, c = x.shape
+    return x.reshape(b // pack, pack, h, w, c).transpose(
+        0, 2, 3, 1, 4).reshape(b // pack, h, w, pack * c)
+
+
+def unpack_images(x, pack: int):
+    """The inverse of `pack_images`."""
+    if pack == 1:
+        return x
+    bp, h, w, pc = x.shape
+    return x.reshape(bp, h, w, pack, pc // pack).transpose(
+        0, 3, 1, 2, 4).reshape(bp * pack, h, w, pc // pack)
 
 
 class CNNModel:
@@ -118,15 +168,34 @@ class CNNModel:
     def init(self, key):
         return L.materialize(self.param_specs(), key, self.cfg.jnp_dtype)
 
-    def _block(self, p, x, stride):
+    def client_pack(self, batch_size: int) -> int:
+        """Images the client segment packs into the channel axis: the
+        largest power of two `p` that divides `batch_size` with `p` times
+        the segment's widest channel count within `LANES`."""
         cfg = self.cfg
-        y = conv(x, p["conv1"], stride)
-        y = jax.nn.relu(group_norm(y, p["gn1"], cfg.group_size))
-        y = conv(y, p["conv2"])
-        y = group_norm(y, p["gn2"], cfg.group_size)
+        c = max(cfg.channels[:max(cfg.cut_stage, 1)])
+        p = 1
+        while 2 * p * c <= LANES and batch_size % (2 * p) == 0:
+            p *= 2
+        return p
+
+    def _norm(self, x, p, pack: int = 1):
+        """GroupNorm of each of the `pack` images packed in `x`: `pack`
+        times the groups, scale and bias tiled, so that no group
+        straddles two images."""
+        groups = group_count(x.shape[-1] // pack, self.cfg.group_size)
+        if pack > 1:
+            p = {k: jnp.tile(v, pack) for k, v in p.items()}
+        return group_norm(x, p, pack * groups)
+
+    def _block(self, p, x, stride, pack: int = 1):
+        y = conv(x, block_diagonal(p["conv1"], pack), stride)
+        y = jax.nn.relu(self._norm(y, p["gn1"], pack))
+        y = conv(y, block_diagonal(p["conv2"], pack))
+        y = self._norm(y, p["gn2"], pack)
         sc = x
         if "proj" in p:
-            sc = conv(x, p["proj"], stride)
+            sc = conv(x, block_diagonal(p["proj"], pack), stride)
         elif stride != 1:
             sc = x[:, ::stride, ::stride]
         return jax.nn.relu(y + sc)
@@ -139,15 +208,16 @@ class CNNModel:
         return x
 
     def client_forward(self, params, batch):
-        cfg = self.cfg
-        x = batch["images"].astype(cfg.jnp_dtype)
-        x = conv(x, params["client"]["stem"])
-        x = jax.nn.relu(group_norm(x, params["client"]["stem_gn"],
-                                   cfg.group_size))
-        for blocks in params["client"]["stages"]:
+        x = batch["images"].astype(self.cfg.jnp_dtype)
+        pack = self.client_pack(x.shape[0])
+        cp = params["client"]
+        x = pack_images(x, pack)
+        x = conv(x, block_diagonal(cp["stem"], pack))
+        x = jax.nn.relu(self._norm(x, cp["stem_gn"], pack))
+        for blocks in cp["stages"]:
             for bp in blocks:
-                x = self._block(bp, x, 1)
-        return x
+                x = self._block(bp, x, 1, pack)
+        return unpack_images(x, pack)
 
     def server_forward(self, server_params, cut_acts):
         x = cut_acts

@@ -408,6 +408,24 @@ def test_psl_counts_its_donated_state_leaves_once():
         == {"donated": n, "leaves": n}
 
 
+def test_psl_counts_its_client_pack_once():
+    """The fused step's client segment packs images into the channel axis;
+    the strategy reports the factor the batch shape gives, once a run."""
+    from repro.obs import Tracer
+    spec = small_spec(epochs=1).replace(
+        execution=api.ExecutionSpec(max_steps=3))
+    ctx = api.build_context(spec)
+    tracer = Tracer()
+    result = api.fit(ctx, api.get_protocol("psl")(),
+                     api.default_callbacks(spec, ctx.data), tracer=tracer)
+    pack = ctx.model.client_pack(spec.protocol.global_batch_size)
+    assert pack == 8        # 16 reduced channels x 8 images = 128 lanes
+    counts = [e["args"]["value"] for e in tracer.events
+              if e["ph"] == "C" and e["name"] == "psl.client_pack"]
+    assert counts == [pack]
+    assert result.history.extras["client_pack"] == pack
+
+
 @pytest.mark.parametrize("name", ["fl", "sfl"])
 def test_per_client_strategies_keep_their_global_params(name):
     ctx = api.build_context(small_spec(name=name, epochs=1))

@@ -10,7 +10,7 @@ from repro.core import ClientPopulation, assign_delays, lds_plan, simulate_tpe, 
 from repro.core.psl import (cut_transfer_bytes, decomposed_grads,
                             make_train_step, slot_weights)
 from repro.models import build_model
-from repro.models.cnn import CNNConfig, CNNModel, group_norm
+from repro.models.cnn import CNNConfig, CNNModel, conv, group_norm
 from repro.configs import get_config
 from repro.optim import TrainState
 
@@ -152,6 +152,70 @@ def test_group_norm_lane_dense_matches_reshape_form(c, groups):
                  for e in _eqns(jax.make_jaxpr(fn)(x, p).jaxpr)
                  if e.primitive.name == "reshape"]
         assert 5 not in ranks, ranks
+
+
+def _unpacked_client_forward(cfg, cp, x):
+    """The client segment with one image per channel group of the batch
+    axis: stem, then stride-1 residual blocks, GroupNorm after each
+    convolution. The formula the packed segment must reproduce."""
+    def norm(x, p):
+        return group_norm(x, p, cfg.group_size)
+
+    x = jax.nn.relu(norm(conv(x, cp["stem"]), cp["stem_gn"]))
+    for blocks in cp["stages"]:
+        for p in blocks:
+            y = jax.nn.relu(norm(conv(x, p["conv1"]), p["gn1"]))
+            y = norm(conv(y, p["conv2"]), p["gn2"])
+            sc = conv(x, p["proj"]) if "proj" in p else x
+            x = jax.nn.relu(y + sc)
+    return x
+
+
+@pytest.mark.parametrize("channels,cut_stage,groups,b,pack", [
+    ((64, 128), 1, 32, 4, 2),       # the paper's stage 0: 2 x 64 lanes
+    ((32, 64), 1, 8, 8, 4),
+    ((16, 32, 64), 2, 8, 4, 4),     # a projection inside the client
+    ((64, 128), 1, 32, 3, 1),       # an odd batch is not packed
+])
+def test_packed_client_segment_matches_unpacked(channels, cut_stage, groups,
+                                                b, pack):
+    """`client_forward` packs `pack` images into the channel axis; its
+    cut and its VJP (client params, images) match the unpacked formula to
+    float32 rounding, and every convolution runs at `pack` times the
+    published channels."""
+    cfg = CNNConfig(channels=channels, cut_stage=cut_stage, image_size=8,
+                    group_size=groups)
+    model = CNNModel(cfg)
+    assert model.client_pack(b) == pack
+    params = model.init(jax.random.PRNGKey(3))
+    rng = np.random.default_rng(b + pack)
+    x = jnp.asarray(rng.normal(size=(b, 8, 8, 3)), jnp.float32)
+
+    def packed(cp, x):
+        return model.client_forward({**params, "client": cp}, {"images": x})
+
+    def unpacked(cp, x):
+        return _unpacked_client_forward(cfg, cp, x)
+
+    cut, vjp = jax.vjp(packed, params["client"], x)
+    cut_ref, vjp_ref = jax.vjp(unpacked, params["client"], x)
+    ct = jnp.asarray(rng.normal(size=cut.shape), jnp.float32)
+    got = jax.tree_util.tree_leaves((cut, vjp(ct)))
+    want = jax.tree_util.tree_leaves((cut_ref, vjp_ref(ct)))
+    assert cut.shape == (b, 8, 8, channels[cut_stage - 1])
+    for a, ref in zip(got, want):
+        a, ref = np.asarray(a), np.asarray(ref)
+        np.testing.assert_allclose(a, ref, rtol=1e-5,
+                                   atol=1e-5 * np.abs(ref).max())
+
+    convs = [e for e in _eqns(jax.make_jaxpr(packed)(params["client"],
+                                                     x).jaxpr)
+             if e.primitive.name == "conv_general_dilated"]
+    assert convs
+    for e in convs:
+        assert e.outvars[0].aval.shape[0] == b // pack
+        assert e.outvars[0].aval.shape[-1] in \
+            {pack * c for c in channels[:cut_stage]}
 
 
 def test_cut_transfer_bytes():
